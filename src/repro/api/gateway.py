@@ -481,7 +481,8 @@ class NorthboundGateway:
             latency_ms=res.latency_ms, queue_wait_ms=res.queue_wait_ms,
             completed=res.completed,
             error_code=m.code_for_cause(res.failed) if res.failed else None,
-            token_ids=res.token_ids, at_s=self.orch.clock.now()))
+            token_ids=res.token_ids, at_s=self.orch.clock.now(),
+            detail=res.detail))
 
     def reap_orphans(self, now: Optional[float] = None) -> int:
         """Abort every prepared-but-never-committed establishment whose

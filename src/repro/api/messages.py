@@ -262,6 +262,8 @@ class ServeComplete(Message):
     error_code: Optional[str] = None
     token_ids: Optional[List[int]] = None
     at_s: float = 0.0
+    #: the refusal's own text when the request failed ("" otherwise)
+    detail: str = ""
     schema_version: str = SCHEMA_VERSION
 
 

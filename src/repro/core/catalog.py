@@ -124,11 +124,19 @@ class Catalog:
 
     def admissible(self, asp: ASP):
         """All entries whose constraints admit this ASP (hard filter of
-        Eq. 7 — ranking happens in discovery)."""
+        Eq. 7 — ranking happens in discovery). A non-empty fallback ladder
+        is the ONLY admissible degradation path: a model it does not name
+        is no candidate, so a session never binds a model other than the
+        one it asked for. The adapter's own base stays admissible (the
+        "base+adapter at the edge" rung)."""
         out = [e for e in self._entries.values() if e.matches(asp)]
-        # honour the fallback ladder ordering when given
         if asp.fallback_ladder:
             order = {m: i for i, (m, _) in enumerate(asp.fallback_ladder)}
+            base = (self.adapters.get(asp.adapter_id).base_model_id
+                    if asp.adapter_id and self.adapters.has(asp.adapter_id)
+                    else None)
+            out = [e for e in out if e.model_id in order
+                   or e.model_id == base]
             out.sort(key=lambda e: order.get(e.model_id, len(order)))
         return out
 

@@ -120,11 +120,12 @@ def decode_attention(q, k, v, lengths, *, block_kv: int = 512,
 
 
 def _paged_kernel(len_ref, tbl_ref, q_ref, k_ref, v_ref, o_ref,
-                  m_ref, l_ref, acc_ref, *, scale: float, page: int):
+                  m_ref, l_ref, acc_ref, *, scale: float, page: int,
+                  heads: int):
     del tbl_ref                       # consumed by the index maps
     b = pl.program_id(0)
-    ip = pl.program_id(2)
-    npg = pl.num_programs(2)
+    ip = pl.program_id(1)
+    npg = pl.num_programs(1)
 
     @pl.when(ip == 0)
     def _init():
@@ -137,27 +138,28 @@ def _paged_kernel(len_ref, tbl_ref, q_ref, k_ref, v_ref, o_ref,
 
     @pl.when(kv_start < length)
     def _compute():
-        q = q_ref[0, 0].astype(jnp.float32)           # [g, d]
-        k = k_ref[0, :, 0].astype(jnp.float32)        # [page, d]
-        v = v_ref[0, :, 0]                            # [page, d]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        k_pos = kv_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(k_pos < length, s, NEG_INF)      # [g, page]
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
-        p = jnp.exp(s - m_new[:, None])
-        alpha = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1)
-        acc_ref[...] = acc_ref[...] * alpha[:, None] + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_ref[...] = m_new
+        for h in range(heads):                 # static: every kv head of
+            q = q_ref[0, h].astype(jnp.float32)       # this page, one DMA
+            k = k_ref[0, :, h].astype(jnp.float32)    # [page, d]
+            v = v_ref[0, :, h]                        # [page, d]
+            s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32) * scale
+            k_pos = kv_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            s = jnp.where(k_pos < length, s, NEG_INF)  # [g, page]
+            m_prev = m_ref[h]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
+            p = jnp.exp(s - m_new[:, None])
+            alpha = jnp.exp(m_prev - m_new)
+            l_ref[h] = l_ref[h] * alpha + jnp.sum(p, axis=1)
+            acc_ref[h] = acc_ref[h] * alpha[:, None] + jax.lax.dot_general(
+                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            m_ref[h] = m_new
 
     @pl.when(ip == npg - 1)
     def _finalize():
         l = jnp.maximum(l_ref[...], 1e-37)
-        o_ref[0, 0] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] / l[..., None]).astype(o_ref.dtype)
 
 
 def paged_decode_attention(q, k_pages, v_pages, lengths, block_tables, *,
@@ -165,11 +167,14 @@ def paged_decode_attention(q, k_pages, v_pages, lengths, block_tables, *,
     """q: [B, Hq, D]; k/v_pages: [P, page, Hkv, D]; lengths: [B];
     block_tables: [B, PPS] int32 page ids -> out [B, Hq, D].
 
-    The kv-block grid dim is the block-table column: grid step (b, h, ip)
-    streams pool page ``block_tables[b, ip]``. Pages past a sequence's
-    length are still DMA'd (whatever the stale table entry points at) but
-    their compute is skipped by the ``kv_start < length`` gate, so garbage
-    and scratch pages never touch the softmax state.
+    The kv-block grid dim is the block-table column: grid step (b, ip)
+    streams pool page ``block_tables[b, ip]`` with ALL its kv heads in one
+    DMA (block ``(1, page, Hkv, D)`` — the TPU lowering refuses a block
+    that slices the second-minor Hkv axis to 1), so each page is read once.
+    Pages past a sequence's length are still DMA'd (whatever the stale
+    table entry points at) but their compute is skipped by the
+    ``kv_start < length`` gate, so garbage and scratch pages never touch
+    the softmax state.
     """
     B, Hq, D = q.shape
     page, Hkv = k_pages.shape[1], k_pages.shape[2]
@@ -178,28 +183,29 @@ def paged_decode_attention(q, k_pages, v_pages, lengths, block_tables, *,
     scale = 1.0 / (D ** 0.5)
 
     qg = q.reshape(B, Hkv, g, D)
-    grid = (B, Hkv, PPS)
-    kern = functools.partial(_paged_kernel, scale=scale, page=page)
+    grid = (B, PPS)
+    kern = functools.partial(_paged_kernel, scale=scale, page=page,
+                             heads=Hkv)
 
-    def kv_map(b, h, ip, lens, tbl):
+    def kv_map(b, ip, lens, tbl):
         del lens
-        return (tbl[b, ip], 0, h, 0)
+        return (tbl[b, ip], 0, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,                       # lengths, block table
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, 1, g, D),
-                         lambda b, h, ip, lens, tbl: (b, h, 0, 0)),
-            pl.BlockSpec((1, page, 1, D), kv_map),
-            pl.BlockSpec((1, page, 1, D), kv_map),
+            pl.BlockSpec((1, Hkv, g, D),
+                         lambda b, ip, lens, tbl: (b, 0, 0, 0)),
+            pl.BlockSpec((1, page, Hkv, D), kv_map),
+            pl.BlockSpec((1, page, Hkv, D), kv_map),
         ],
-        out_specs=pl.BlockSpec((1, 1, g, D),
-                               lambda b, h, ip, lens, tbl: (b, h, 0, 0)),
+        out_specs=pl.BlockSpec((1, Hkv, g, D),
+                               lambda b, ip, lens, tbl: (b, 0, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((g,), jnp.float32),
-            pltpu.VMEM((g,), jnp.float32),
-            pltpu.VMEM((g, D), jnp.float32),
+            pltpu.VMEM((Hkv, g), jnp.float32),
+            pltpu.VMEM((Hkv, g), jnp.float32),
+            pltpu.VMEM((Hkv, g, D), jnp.float32),
         ],
     )
     out = pl.pallas_call(

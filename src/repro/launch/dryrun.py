@@ -11,7 +11,7 @@ if os.environ.get("REPRO_DRYRUN_DEVICES"):
 For every (architecture × input shape) cell, on the single-pod 16×16 mesh
 and the 2×16×16 multi-pod mesh:
 
-    with mesh:
+    with jax.set_mesh(mesh):
         lowered = jax.jit(step, in_shardings=…, donate…).lower(*input_specs)
         compiled = lowered.compile()
         print(compiled.memory_analysis())   # proves it fits
@@ -84,7 +84,7 @@ def lower_cell(arch: str, shape_name: str, mesh, *, scale: float = 1.0,
         in_sh = (jax.tree.map(lambda s: NamedSharding(mesh, s), state_specs,
                               is_leaf=lambda x: isinstance(x, P)),
                  _batch_shardings(mesh, plan, specs))
-        with mesh:
+        with jax.set_mesh(mesh):
             lowered = jax.jit(step, in_shardings=in_sh,
                               donate_argnums=(0,)).lower(state_abs, specs)
     elif cell.kind == "prefill":
@@ -102,7 +102,7 @@ def lower_cell(arch: str, shape_name: str, mesh, *, scale: float = 1.0,
         # the output cache is the session state: shard it like the decode
         # cache, else XLA leaves it batch-sharded only (13 GB/device observed)
         out_sh = (NamedSharding(mesh, P()), _shard(mesh, plan.cache_specs))
-        with mesh:
+        with jax.set_mesh(mesh):
             lowered = jax.jit(prefill_step, in_shardings=in_sh,
                               out_shardings=out_sh).lower(params_abs, specs)
     else:  # decode / serve_step
@@ -120,7 +120,7 @@ def lower_cell(arch: str, shape_name: str, mesh, *, scale: float = 1.0,
         in_sh = (_shard(mesh, plan.param_specs),
                  _shard(mesh, plan.cache_specs),
                  NamedSharding(mesh, plan.batch_specs["tokens"]))
-        with mesh:
+        with jax.set_mesh(mesh):
             lowered = jax.jit(serve_step, in_shardings=in_sh,
                               donate_argnums=(1,)).lower(
                                   params_abs, cache_abs, specs["tokens"])

@@ -25,9 +25,9 @@ serving engine):
   admitting or exporting a session no longer materialises a second full
   cache.
 
-On the CPU container this runs the tiny models for examples/tests; on a pod
-the same code jit-compiles under the production mesh with the decode plan's
-shardings (see repro.launch.serve).
+The engine is single-device: its params and cache live on the process's
+first device (a TPU chip, or the CPU in the tests, where Pallas kernels run
+in interpret mode). No serving code builds a mesh yet.
 """
 
 from __future__ import annotations
@@ -49,6 +49,12 @@ from repro.models import kvcache as KV
 #: smallest prefill bucket — below this the compile is cheap enough that
 #: further splitting buys nothing
 _MIN_BUCKET = 16
+
+
+class RequestRefused(ValueError):
+    """A request this engine cannot serve as asked (prompt longer than
+    ``max_len``, adapter not loaded). The serving plane answers it with a
+    failed result (NO_FEASIBLE_BINDING); any other error is a fault."""
 
 
 class PagePoolExhausted(RuntimeError):
@@ -589,20 +595,20 @@ class InferenceEngine:
         compiles at most ``len(self.buckets)`` prefill variants.
 
         ``adapter_id`` binds a tenant adapter for the session's lifetime;
-        it must already be loaded on this engine (ValueError otherwise —
-        the serving plane maps that to NO_FEASIBLE_BINDING).
+        it must already be loaded on this engine (RequestRefused otherwise
+        — the serving plane maps that to NO_FEASIBLE_BINDING).
         """
         t0 = time.perf_counter()
         aidx = 0
         if adapter_id:
             if self.adapters is None:
-                raise ValueError(
+                raise RequestRefused(
                     f"engine has no adapter runtime; cannot bind "
                     f"{adapter_id!r} for {session_id}")
             try:
                 aidx = self.adapters.index_of(adapter_id)
             except KeyError:
-                raise ValueError(
+                raise RequestRefused(
                     f"adapter {adapter_id!r} not loaded on this engine "
                     f"for {session_id}")
         n = len(prompt)
@@ -610,7 +616,7 @@ class InferenceEngine:
             # refuse rather than silently truncate: a truncated prefill
             # would condition generation on a clipped prefix while
             # position_of()/migration payload sizing report the full length
-            raise ValueError(
+            raise RequestRefused(
                 f"prompt of {n} tokens exceeds engine max_len "
                 f"{self.max_len} for {session_id}")
         width = self._bucket(n)

@@ -43,6 +43,7 @@ import numpy as np
 
 from repro.core.clock import Clock, VirtualClock
 from repro.core.failures import FailureCause
+from repro.serving.engine import PagePoolExhausted, RequestRefused
 from repro.serving.scheduler import QoSScheduler, Request
 
 
@@ -60,6 +61,7 @@ class PlaneResult:
     failed: Optional[FailureCause] = None
     token_ids: Optional[List[int]] = None   # real-engine backends only
     prompt_tokens: int = 0       # context consumed (sizes migration payload)
+    detail: str = ""             # why a failed request failed (refusal text)
 
 
 @dataclass
@@ -535,15 +537,19 @@ class ServingPlane:
                 self._active_sessions | {req.session_id})
             try:
                 adm = self.backend.admit(req, self.clock.now())
-            except Exception as e:
-                # the request is already in scheduler.running — a backend
-                # refusal (oversized prompt, engine failure) must free that
-                # slot and surface as a failed result, never wedge the site
+            except (RequestRefused, PagePoolExhausted) as e:
+                # the request is already in scheduler.running — a designed
+                # refusal (infeasible request, no KV pages) must free that
+                # slot and surface as a failed result, never wedge the site.
+                # Anything else (runtime, compiler, device memory) is a
+                # fault of the site, not an answer to the request: it
+                # propagates instead of posing as a busy site
                 self.scheduler.detach(req.request_id)
                 cause = (FailureCause.NO_FEASIBLE_BINDING
-                         if isinstance(e, ValueError)   # infeasible request
+                         if isinstance(e, RequestRefused)
                          else FailureCause.COMPUTE_SCARCITY)
-                self._finish(req, ttfb_ms=0.0, completed=False, failed=cause)
+                self._finish(req, ttfb_ms=0.0, completed=False, failed=cause,
+                             detail=f"{type(e).__name__}: {e}")
                 continue
             self._active_sessions.add(req.session_id)
             req.hint_ttfb_ms = adm.ttfb_ms            # measured/known TTFB
@@ -564,7 +570,8 @@ class ServingPlane:
                     self._tok_ids[req.request_id] = [adm.first_token]
 
     def _finish(self, req: Request, *, ttfb_ms: float, completed: bool,
-                failed: Optional[FailureCause] = None) -> None:
+                failed: Optional[FailureCause] = None,
+                detail: str = "") -> None:
         now = self.clock.now()
         latency_ms = (now - req.submitted_at) * 1e3
         started = req.started_at if req.started_at is not None else now
@@ -576,7 +583,7 @@ class ServingPlane:
             tokens=self._tokens.pop(req.request_id, 0),
             completed=completed and failed is None, failed=failed,
             token_ids=self._tok_ids.pop(req.request_id, None),
-            prompt_tokens=req.prompt_tokens)
+            prompt_tokens=req.prompt_tokens, detail=detail)
         self._done[req.request_id] = res
         self._outbox.append(res)
         self._by_request.pop(req.request_id, None)

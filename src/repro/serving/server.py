@@ -113,7 +113,8 @@ class AIaaSServer:
                 tokens=res.tokens, completed=res.completed,
                 failed=wire.cause_for_code(res.error_code)
                 if res.error_code else None,
-                token_ids=res.token_ids, prompt_tokens=res.prompt_tokens)
+                token_ids=res.token_ids, prompt_tokens=res.prompt_tokens,
+                detail=res.detail)
         return out
 
     # ------------------------------------------------------------------

@@ -1,11 +1,12 @@
 """Ambient-mesh activation sharding constraints (MaxText-style logical axes).
 
 Model code calls ``constrain(x, "dp", None, "model", ...)`` at key points;
-under a ``with mesh:`` lowering context this pins the activation layout so
-GSPMD cannot drift into batch-replicated layouts inside scan bodies (observed
-failure mode: 25 GB/device of batch-replicated attention residuals — see
-EXPERIMENTS.md §Perf iteration 0). Outside any mesh (CPU smoke tests) it is
-an identity, keeping the model code mesh-agnostic.
+under a ``with jax.set_mesh(mesh):`` lowering context this pins the
+activation layout so GSPMD cannot drift into batch-replicated layouts
+inside scan bodies (observed failure mode: 25 GB/device of batch-replicated
+attention residuals — see EXPERIMENTS.md §Perf iteration 0). Outside any
+mesh (CPU smoke tests) it is an identity, keeping the model code
+mesh-agnostic.
 
 Dim tokens:
     "dp"    — shard over the data-parallel axes (pod+data) if divisible
@@ -18,12 +19,13 @@ from __future__ import annotations
 import numpy as np
 
 import jax
-from jax.interpreters import pxla
 from jax.sharding import PartitionSpec as P
 
 
 def current_mesh():
-    m = pxla.thread_resources.env.physical_mesh
+    """The (abstract) mesh of the enclosing ``jax.set_mesh`` context, or
+    None outside one. Readable while tracing, unlike ``get_mesh``."""
+    m = jax.sharding.get_abstract_mesh()
     return None if m.empty else m
 
 

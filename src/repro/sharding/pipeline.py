@@ -19,7 +19,6 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 
 def pipeline_forward(stage_fn: Callable, mesh: Mesh, *, num_microbatches: int,
@@ -72,11 +71,11 @@ def pipeline_forward(stage_fn: Callable, mesh: Mesh, *, num_microbatches: int,
             outs = jnp.where(idx == n_stages - 1, outs, 0.0)
             return jax.lax.psum(outs, axis)
 
-        return shard_map(
+        return jax.shard_map(
             body, mesh=mesh,
             in_specs=(P(axis), P()),          # stage params sharded, x replicated
             out_specs=P(),
-            check_rep=False,
+            check_vma=False,
         )(stage_params, x)
 
     return pipelined

@@ -105,3 +105,14 @@ class TestPaging:
         kinds = {"edge": 0, "regional": 1, "central": 2}
         assert kinds[sites[c_vehic.site_id].spec.kind] >= \
             kinds[sites[c_static.site_id].spec.kind]
+
+    @pytest.mark.parametrize("model", ["edge-tiny", "mamba2-1.3b",
+                                       "minitron-8b"])
+    def test_hinted_asp_binds_only_its_model(self, world, model):
+        """A fallback ladder is the only admissible degradation path: a
+        hinted ASP never pages onto a model it did not name."""
+        clock, catalog, sites, analytics, predictors = world
+        asp = default_asp(model, tier=catalog.get(model).tier)
+        cands = discover(asp, catalog, sites, predictors, "zone-a")
+        assert {c.model.model_id for c in cands} == {model}
+        assert page(asp, cands).model.model_id == model

@@ -67,7 +67,7 @@ def real_server():
 
 
 class TestRoundTripFingerprint:
-    @settings(max_examples=5)
+    @settings(max_examples=5, deadline=None)
     @given(arch=st.sampled_from(sorted(FAMILIES)),
            prompt_len=st.integers(min_value=4, max_value=20),
            rounds=st.integers(min_value=0, max_value=5))
@@ -192,7 +192,7 @@ class TestZeroInterruption:
             assert out.interruption_ms == 0.0
             assert s.committed() and s.binding.site_id == out.from_site
 
-    @settings(max_examples=4)
+    @settings(max_examples=4, deadline=None)
     @given(pre_rounds=st.integers(min_value=0, max_value=4),
            gen=st.integers(min_value=8, max_value=16))
     def test_real_engine_mid_stream_never_gaps(self, pre_rounds, gen):

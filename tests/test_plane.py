@@ -17,7 +17,8 @@ from repro.core.asp import MobilityClass
 from repro.core.clock import VirtualClock
 from repro.core.failures import FailureCause
 from repro.core.migration import MigrationTriggers
-from repro.serving.engine import InferenceEngine
+from repro.serving.engine import (InferenceEngine, PagePoolExhausted,
+                                  RequestRefused)
 from repro.serving.plane import ServingPlane, SimulatedEngine
 from repro.serving.scheduler import QoSScheduler, Request
 
@@ -307,3 +308,57 @@ class TestPlaneRealEngine:
         results = srv.drain()
         assert any(res.session_id == s.session_id and res.failed is None
                    for res in results.values())
+
+
+class _FailingBackend:
+    """A backend whose every admission raises ``exc``."""
+    exclusive_sessions = True
+    needs_service_hints = False
+
+    def __init__(self, exc):
+        self.exc = exc
+
+    def predicted_service_ms(self, req):
+        return 0.0
+
+    def ensure_capacity(self, active_sessions):
+        pass
+
+    def admit(self, req, now):
+        raise self.exc
+
+    def decode_round(self, steps=None):
+        return {}
+
+    def release(self, session_id):
+        pass
+
+
+def _submit_one(plane):
+    return plane.submit(session_id="s", klass="premium", prompt_tokens=8,
+                        gen_tokens=4, t_max_ms=10_000.0)
+
+
+class TestAdmissionFailures:
+    @pytest.mark.parametrize("exc,cause", [
+        (RequestRefused("prompt of 99 tokens exceeds engine max_len 64"),
+         FailureCause.NO_FEASIBLE_BINDING),
+        (PagePoolExhausted("page pool exhausted: need 2 pages, 0 free"),
+         FailureCause.COMPUTE_SCARCITY),
+    ], ids=["refused", "no-pages"])
+    def test_designed_refusal_is_a_failed_result(self, exc, cause):
+        """Refusals free the slot and carry their text to the invoker."""
+        plane = ServingPlane(VirtualClock(), _FailingBackend(exc), slots=2)
+        _submit_one(plane)
+        (res,) = plane.pop_results()
+        assert res.failed is cause and str(exc) in res.detail
+        assert not plane.scheduler.running
+
+    def test_device_fault_propagates(self):
+        """A runtime, compiler or device-memory error is a fault of the
+        site, not a busy site: it must not become a failed result."""
+        err = RuntimeError("RESOURCE_EXHAUSTED: out of HBM")
+        plane = ServingPlane(VirtualClock(), _FailingBackend(err), slots=2)
+        with pytest.raises(RuntimeError, match="RESOURCE_EXHAUSTED"):
+            _submit_one(plane)
+        assert not plane.pop_results()

@@ -1,5 +1,7 @@
 """Serving plane: continuous batching, QoS scheduler, state transfer."""
 
+import os
+
 import numpy as np
 import pytest
 
@@ -125,3 +127,39 @@ class TestScheduler:
         s.complete(batch[0].request_id)
         assert s.stats.completed == 1
         assert not s.running
+
+
+class TestLauncher:
+    def test_serve_binds_sessions_to_the_served_model(self):
+        """Every session of serve() binds the model its site engines run
+        (the ASP names it as the only ladder rung), even where the
+        alternating session tier asks for more than the model offers."""
+        from repro.launch.serve import serve
+        rep = serve("edge-tiny", sessions=2, requests=2, slots=2,
+                    max_len=64, gen_tokens=2, quiet=True)
+        assert rep.model_id == "edge-tiny"
+        assert set(rep.bound.values()) == {"edge-tiny@1.0"}
+        assert not rep.mismatched() and not rep.failed
+        assert rep.served == rep.sent == 2
+        assert all(len(t) == 2 for t in rep.tokens.values())
+
+    def test_compile_cache_dir_is_fixed_or_the_environments(self,
+                                                            monkeypatch):
+        import jax
+        from repro.launch.compile_cache import enable_compile_cache
+        before = jax.config.jax_compilation_cache_dir
+        try:
+            monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere")
+            assert enable_compile_cache() == "/elsewhere"
+            assert jax.config.jax_compilation_cache_dir == before
+            monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+            path = enable_compile_cache()
+            repo = os.path.dirname(os.path.dirname(os.path.abspath(
+                __file__)))
+            assert path == os.path.join(repo, ".jax_cache")
+            assert jax.config.jax_compilation_cache_dir == path
+            assert enable_compile_cache("/srv/aiaas") == \
+                os.path.join("/srv/aiaas", ".jax_cache")
+        finally:
+            jax.config.update("jax_compilation_cache_dir", before)
+
