@@ -1,0 +1,10 @@
+"""Share of the traced window in which no operation ran on the device (1
+minus the union of the device's operation intervals over the window), in
+percent."""
+
+
+def read(run):
+    t = run.trace
+    if not t["window_s"] or not t["devices"]:
+        return None
+    return 100.0 * (1.0 - t["busy_s"] / t["window_s"])

@@ -1,0 +1,8 @@
+import os
+import sys
+from pathlib import Path
+
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+BENCH = Path(__file__).resolve().parents[1]
+for p in (BENCH, BENCH / "tests", BENCH.parent / "src"):
+    sys.path.insert(0, str(p))
