@@ -49,6 +49,7 @@ from repro.core.failures import FailureCause, SessionError
 from repro.core.migration import MigrationTriggers
 from repro.core.orchestrator import Orchestrator
 from repro.core.session import AISession, SessionState
+from repro.obs import span
 
 Reply = Union[m.Message, List[m.Message]]
 
@@ -128,13 +129,18 @@ class NorthboundGateway:
         except (TypeError, KeyError) as e:
             return m.ErrorResponse("E_BAD_REQUEST",
                                    detail=repr(e)).to_json()
-        out = self.handle(msg)
-        if isinstance(out, list):
-            return [o.to_json() for o in out]
-        return out.to_json()
+        with _handle_span(msg):
+            out = self._handle(msg)
+            if isinstance(out, list):
+                return [o.to_json() for o in out]
+            return out.to_json()
 
     def handle(self, msg: m.Message) -> Reply:
         """Typed dispatch (the JSON path normalizes into here)."""
+        with _handle_span(msg):
+            return self._handle(msg)
+
+    def _handle(self, msg: m.Message) -> Reply:
         ver = getattr(msg, "schema_version", m.SCHEMA_VERSION)
         if str(ver).split(".")[0] != m.SCHEMA_VERSION.split(".")[0]:
             return m.ErrorResponse(
@@ -530,11 +536,12 @@ class NorthboundGateway:
     def pump(self, until_s: float) -> None:
         """Advance every site plane to absolute time ``until_s`` (virtual
         clocks) and record the completions that fell due."""
-        for site in self.orch.sites.values():
-            if site.plane is not None:
-                site.plane.run_until(until_s)
-                self.orch.record_results(site)
-        self.reap_orphans()
+        with span("gateway.pump"):
+            for site in self.orch.sites.values():
+                if site.plane is not None:
+                    site.plane.run_until(until_s)
+                    self.orch.record_results(site)
+            self.reap_orphans()
 
     def drain(self) -> List[m.ServeComplete]:
         """Run every plane to completion and return ALL completions
@@ -729,6 +736,11 @@ class NorthboundGateway:
         m.LoadAdapterRequest: load_adapter,
         m.UnloadAdapterRequest: unload_adapter,
     }
+
+
+def _handle_span(msg: m.Message):
+    return span("gateway.handle", type=msg.TYPE,
+                sid=getattr(msg, "session_id", None) or "")
 
 
 class _Unknown(Exception):

@@ -33,6 +33,7 @@ from repro.core.sites import ExecutionSite, default_sites
 from repro.core.telemetry import BoundaryTelemetry, RequestRecord
 from repro.core.twophase import TwoPhaseCoordinator
 from repro.netfault.breaker import BreakerBoard
+from repro.obs import span
 
 
 @dataclass
@@ -132,46 +133,51 @@ class Orchestrator:
         a federation attached, this is home-routed: local candidates first,
         east-west offers merged in (per the domain's solicit policy) with
         exclusion reasons prefixed by the owning domain."""
-        t0 = self.clock.now()
-        cands = discover(session.asp, self.catalog, self.sites,
-                         self.predictors, session.zone,
-                         analytics=self.analytics, breakers=self.breakers)
-        if self.federation is not None:
-            cands = self.federation.augment(session, cands)
-        if self.clock.now() - t0 > self.timers.tau_disc:
-            raise SessionError(FailureCause.DEADLINE_EXPIRY,
-                               "DISCOVER exceeded τ_disc")
-        session.mark_discovered()
-        return cands
+        with span("orch.discover"):
+            t0 = self.clock.now()
+            cands = discover(session.asp, self.catalog, self.sites,
+                             self.predictors, session.zone,
+                             analytics=self.analytics, breakers=self.breakers)
+            if self.federation is not None:
+                cands = self.federation.augment(session, cands)
+            if self.clock.now() - t0 > self.timers.tau_disc:
+                raise SessionError(FailureCause.DEADLINE_EXPIRY,
+                                   "DISCOVER exceeded τ_disc")
+            session.mark_discovered()
+            return cands
 
     def page_for(self, session: AISession, cands: list,
                  exclude_sites: tuple = ()):
         """AI-PAGING (Eq. 9) + policy admission against the chosen anchor."""
-        chosen = page(session.asp, cands, exclude_sites=exclude_sites)
-        session.mark_anchored()
-        # cost-envelope admission (policy role)
-        self.policy.admit_cost(session.asp, chosen.prediction.cost_per_1k)
-        # sovereignty re-check against the concrete site (consent scope);
-        # east-west offers carry the region — the remote site table doesn't
-        # exist here
-        region = chosen.region or self.sites[chosen.site_id].spec.region
-        self.policy.check_region(session.authz_ref, region)
-        return chosen
+        with span("orch.page"):
+            chosen = page(session.asp, cands, exclude_sites=exclude_sites)
+            session.mark_anchored()
+            # cost-envelope admission (policy role)
+            self.policy.admit_cost(session.asp, chosen.prediction.cost_per_1k)
+            # sovereignty re-check against the concrete site (consent
+            # scope); east-west offers carry the region — the remote site
+            # table doesn't exist here
+            region = chosen.region or self.sites[chosen.site_id].spec.region
+            self.policy.check_region(session.authz_ref, region)
+            return chosen
 
     def prepare_for(self, session: AISession, chosen):
         """PREPARE: provisional co-reservation on both planes (2PC stage 1).
         A remote candidate routes the compute half east-west; the home
         domain keeps only its transport share."""
-        self._check_adapter_binding(session, chosen)
-        session.mark_preparing()
-        if self.federation is not None and self.federation.is_remote(chosen):
-            prepared = self.federation.prepare_remote(session, chosen)
-        else:
-            prepared = self.coordinator.prepare(
-                chosen.model, chosen.site_id, session.zone, chosen.klass,
-                slots=1, cache_bytes=chosen.model.session_state_bytes(2048))
-        session.mark_prepared()
-        return prepared
+        with span("orch.prepare"):
+            self._check_adapter_binding(session, chosen)
+            session.mark_preparing()
+            if self.federation is not None \
+                    and self.federation.is_remote(chosen):
+                prepared = self.federation.prepare_remote(session, chosen)
+            else:
+                prepared = self.coordinator.prepare(
+                    chosen.model, chosen.site_id, session.zone, chosen.klass,
+                    slots=1,
+                    cache_bytes=chosen.model.session_state_bytes(2048))
+            session.mark_prepared()
+            return prepared
 
     def _check_adapter_binding(self, session: AISession, chosen) -> None:
         """Fail fast at PREPARE when the ASP names an adapter this
@@ -202,15 +208,17 @@ class Orchestrator:
         """COMMIT: confirm both leases, bind, open charging + telemetry.
         For a cross-domain PREPARE the visited half stays provisional until
         this home COMMIT lands; failure on either side rolls both back."""
-        if getattr(prepared, "is_federated", False):
-            binding = self.federation.commit_remote(session, chosen,
-                                                    prepared)
-        else:
-            binding = self.coordinator.commit(prepared, chosen.model)
-        session.charging_ref = self.policy.open_charging(session.session_id)
-        session.bind(binding)
-        self.telemetry[session.session_id] = BoundaryTelemetry()
-        return session
+        with span("orch.commit"):
+            if getattr(prepared, "is_federated", False):
+                binding = self.federation.commit_remote(session, chosen,
+                                                        prepared)
+            else:
+                binding = self.coordinator.commit(prepared, chosen.model)
+            session.charging_ref = self.policy.open_charging(
+                session.session_id)
+            session.bind(binding)
+            self.telemetry[session.session_id] = BoundaryTelemetry()
+            return session
 
     def establish(self, asp: ASP, invoker: str, zone: str) -> AISession:
         """DISCOVER → PAGING → PREPARE/COMMIT under Eq. (11) deadlines."""
@@ -267,15 +275,16 @@ class Orchestrator:
         whichever path pops it first. A guest view delegates to the OWNING
         domain's recorder (which meters wholesale and forwards roaming
         results home) so two domains never race on one plane's results."""
-        if getattr(site, "is_guest_view", False):
-            return site.record_results()
-        plane = site.plane
-        if plane is None:
-            return []
-        popped = plane.pop_results()
-        for res in popped:
-            self._record_one(site, res)
-        return popped
+        with span("orch.record_results"):
+            if getattr(site, "is_guest_view", False):
+                return site.record_results()
+            plane = site.plane
+            if plane is None:
+                return []
+            popped = plane.pop_results()
+            for res in popped:
+                self._record_one(site, res)
+            return popped
 
     def _record_one(self, site, res, *, price_override=None) -> None:
         """Record ONE drained PlaneResult: telemetry, context accounting,
@@ -376,16 +385,17 @@ class Orchestrator:
         driving it (batched serving / open-loop simulation); returns the
         scheduler Request, or None when admission control rejects it.
         Completions surface through ``record_results`` → ``result_sinks``."""
-        site, model, plane, klass = self._serve_checked(session)
-        hint_ttfb, hint_total = self._service_hints(
-            session, plane, model, site, klass, prompt_tokens, gen_tokens)
-        return plane.submit(
-            session_id=session.session_id, klass=klass.name,
-            prompt_tokens=prompt_tokens, gen_tokens=gen_tokens,
-            t_max_ms=self._effective_t_max(session, deadline_ms),
-            hint_ttfb_ms=hint_ttfb, hint_total_ms=hint_total,
-            request_id=request_id, prompt=prompt,
-            adapter_id=session.asp.adapter_id)
+        with span("orch.submit"):
+            site, model, plane, klass = self._serve_checked(session)
+            hint_ttfb, hint_total = self._service_hints(
+                session, plane, model, site, klass, prompt_tokens, gen_tokens)
+            return plane.submit(
+                session_id=session.session_id, klass=klass.name,
+                prompt_tokens=prompt_tokens, gen_tokens=gen_tokens,
+                t_max_ms=self._effective_t_max(session, deadline_ms),
+                hint_ttfb_ms=hint_ttfb, hint_total_ms=hint_total,
+                request_id=request_id, prompt=prompt,
+                adapter_id=session.asp.adapter_id)
 
     # ------------------------------------------------------------------
     def serve(self, session: AISession, *, prompt_tokens: int = 512,
@@ -423,6 +433,12 @@ class Orchestrator:
                   triggers: Optional[MigrationTriggers] = None
                   ) -> Optional[MigrationOutcome]:
         """Renew leases; fire Eq. (14) migration when risk crosses δ."""
+        with span("orch.heartbeat"):
+            return self._heartbeat(session, triggers)
+
+    def _heartbeat(self, session: AISession,
+                   triggers: Optional[MigrationTriggers]
+                   ) -> Optional[MigrationOutcome]:
         # heartbeat cadence doubles as the orphan sweep: provisional 2PC
         # leases whose COMMIT/ABORT was lost in flight are aborted once
         # their τ_prep + τ_com + hold window passes (timers are enforced)

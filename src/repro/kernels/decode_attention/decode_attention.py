@@ -102,6 +102,7 @@ def decode_attention(q, k, v, lengths, *, block_kv: int = 512,
             pltpu.VMEM((g, D), jnp.float32),
         ],
         interpret=interpret,
+        name="decode_attention",
     )(lengths.astype(jnp.int32), qg, k, v)
     return out.reshape(B, Hq, D)
 
@@ -213,6 +214,7 @@ def paged_decode_attention(q, k_pages, v_pages, lengths, block_tables, *,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, g, D), q.dtype),
         interpret=interpret,
+        name="paged_flash_decode",
     )(lengths.astype(jnp.int32), block_tables.astype(jnp.int32),
       qg, k_pages, v_pages)
     return out.reshape(B, Hq, D)

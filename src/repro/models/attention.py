@@ -353,13 +353,22 @@ def decode_self_attention(p, cfg: ModelConfig, x, cache_k, cache_v, position,
     select-write.
     """
     b = x.shape[0]
-    S = cache_k.shape[1]
-    kh, hd, hq = cfg.num_kv_heads, cfg.head_dim, cfg.num_heads
-    g = hq // kh
     position = jnp.broadcast_to(jnp.asarray(position, jnp.int32), (b,))
-    q = _project_q(p, cfg, x, position[:, None])
-    k_new, v_new = _project_kv(p, cfg, x, position[:, None])
+    with jax.named_scope("qkv"):
+        q = _project_q(p, cfg, x, position[:, None])
+        k_new, v_new = _project_kv(p, cfg, x, position[:, None])
+    with jax.named_scope("kv_write"):
+        cache_k, cache_v = _decode_cache_write(
+            cfg, cache_k, cache_v, k_new, v_new, position, window, active)
+    with jax.named_scope("attn"):
+        return _decode_attend(p, cfg, x, q, cache_k, cache_v, position,
+                              window)
 
+
+def _decode_cache_write(cfg: ModelConfig, cache_k, cache_v, k_new, v_new,
+                        position, window, active):
+    """Write each row's new K/V at its position of the dense buffer."""
+    b, S = cache_k.shape[0], cache_k.shape[1]
     slot = (position % S) if window else jnp.minimum(position, S - 1)
     if cfg.decode_cache_scatter:          # legacy insert (A/B lever)
         rows = jnp.arange(b)
@@ -381,7 +390,16 @@ def decode_self_attention(p, cfg: ModelConfig, x, cache_k, cache_v, position,
         hit = hit[..., None, None]
         cache_k = jnp.where(hit, k_new, cache_k)
         cache_v = jnp.where(hit, v_new, cache_v)
+    return cache_k, cache_v
 
+
+def _decode_attend(p, cfg: ModelConfig, x, q, cache_k, cache_v, position,
+                   window):
+    """The new token's attention over the written buffer, through the
+    output projection."""
+    b, S = x.shape[0], cache_k.shape[1]
+    kh, hd, hq = cfg.num_kv_heads, cfg.head_dim, cfg.num_heads
+    g = hq // kh
     if cfg.use_pallas_decode and not window and not cfg.attn_logits_softcap:
         # flash-decode Pallas kernel: linear buffer only (slot index IS the
         # absolute position, so the kernel's `kpos < length` ragged mask is
@@ -446,25 +464,38 @@ def paged_decode_self_attention(p, cfg: ModelConfig, x, k_pages, v_pages,
     """
     b = x.shape[0]
     page = k_pages.shape[1]
-    PPS = block.shape[1]
-    S = PPS * page
-    kh, hd, hq = cfg.num_kv_heads, cfg.head_dim, cfg.num_heads
-    g = hq // kh
+    S = block.shape[1] * page
     position = jnp.broadcast_to(jnp.asarray(position, jnp.int32), (b,))
-    q = _project_q(p, cfg, x, position[:, None])
-    k_new, v_new = _project_kv(p, cfg, x, position[:, None])
+    with jax.named_scope("qkv"):
+        q = _project_q(p, cfg, x, position[:, None])
+        k_new, v_new = _project_kv(p, cfg, x, position[:, None])
 
     # write the new token's K/V through the block table (one page row per
     # batch row — distinct active slots never share a page, so the batched
     # scatter has no write conflicts outside the scratch page)
-    posc = jnp.minimum(position, S - 1)
-    pid = jnp.take_along_axis(block, (posc // page)[:, None], axis=1)[:, 0]
-    if active is not None:
-        pid = jnp.where(active, pid, 0)
-    off = posc % page
-    k_pages = k_pages.at[pid, off].set(k_new[:, 0].astype(k_pages.dtype))
-    v_pages = v_pages.at[pid, off].set(v_new[:, 0].astype(v_pages.dtype))
+    with jax.named_scope("kv_write"):
+        posc = jnp.minimum(position, S - 1)
+        pid = jnp.take_along_axis(block, (posc // page)[:, None],
+                                  axis=1)[:, 0]
+        if active is not None:
+            pid = jnp.where(active, pid, 0)
+        off = posc % page
+        k_pages = k_pages.at[pid, off].set(
+            k_new[:, 0].astype(k_pages.dtype))
+        v_pages = v_pages.at[pid, off].set(
+            v_new[:, 0].astype(v_pages.dtype))
+    with jax.named_scope("attn"):
+        return _paged_attend(p, cfg, x, q, k_pages, v_pages, block,
+                             position)
 
+
+def _paged_attend(p, cfg: ModelConfig, x, q, k_pages, v_pages, block,
+                  position):
+    """The new token's attention over its slot's pages, through the
+    output projection."""
+    b, S = x.shape[0], block.shape[1] * k_pages.shape[1]
+    kh, hd, hq = cfg.num_kv_heads, cfg.head_dim, cfg.num_heads
+    g = hq // kh
     if cfg.use_pallas_decode and not cfg.attn_logits_softcap:
         # paged flash-decode kernel: gathers K/V pages through the block
         # table with scalar-prefetch index maps (no [b, S] materialisation)

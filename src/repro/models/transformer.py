@@ -130,25 +130,29 @@ def _block_prefill(p, cfg: ModelConfig, kind: str, x, positions, S,
     # attention kinds
     pos1d = positions[0] if positions.ndim == 3 else positions
     qpos = pos1d[0] if pos1d.ndim == 2 else pos1d
-    k, v = A._project_kv(p["attn"], cfg, h, positions)
-    q = A._project_q(p["attn"], cfg, h, positions)
-    o = A.full_attention(q, k, v, qpos, qpos, cfg, causal=True)
-    b, s = x.shape[0], x.shape[1]
-    y = jnp.einsum("bsq,qd->bsd", o.reshape(b, s, cfg.q_dim),
-                   as_weight(p["attn"]["w_o"]),
-                   preferred_element_type=jnp.float32).astype(x.dtype)
+    with jax.named_scope("qkv"):
+        k, v = A._project_kv(p["attn"], cfg, h, positions)
+        q = A._project_q(p["attn"], cfg, h, positions)
+    with jax.named_scope("attn"):
+        o = A.full_attention(q, k, v, qpos, qpos, cfg, causal=True)
+        b, s = x.shape[0], x.shape[1]
+        y = jnp.einsum("bsq,qd->bsd", o.reshape(b, s, cfg.q_dim),
+                       as_weight(p["attn"]["w_o"]),
+                       preferred_element_type=jnp.float32).astype(x.dtype)
     x = x + y
-    cache = _kv_to_buffer(cfg, k, v, S, length=length)
+    with jax.named_scope("kv_write"):
+        cache = _kv_to_buffer(cfg, k, v, S, length=length)
     if kind == "attn_cross":
         hx = L.rmsnorm_apply(p["norm_x"], x, cfg.norm_eps)
         x = x + A.cross_attention(p["xattn"], cfg, hx, memory, mem_positions)
         ck, cv = A.project_cross_kv(p["xattn"], cfg, memory)
         cache["cross_k"], cache["cross_v"] = ck, cv
     h2 = L.rmsnorm_apply(p["norm2"], x, cfg.norm_eps)
-    if kind == "attn_moe":
-        y2, aux = MOE.moe_apply(p["moe"], cfg, h2)
-    else:
-        y2 = L.mlp_apply(p["mlp"], h2)
+    with jax.named_scope("mlp"):
+        if kind == "attn_moe":
+            y2, aux = MOE.moe_apply(p["moe"], cfg, h2)
+        else:
+            y2 = L.mlp_apply(p["mlp"], h2)
     return x + y2, cache, aux
 
 
@@ -243,10 +247,11 @@ def _block_decode(p, cfg: ModelConfig, kind: str, x, cache_layer, position,
                                          cache_layer["cross_v"],
                                          jnp.arange(src))
     h2 = L.rmsnorm_apply(p["norm2"], x, cfg.norm_eps)
-    if kind == "attn_moe":
-        y2, _ = MOE.moe_apply(p["moe"], cfg, h2)
-    else:
-        y2 = L.mlp_apply(p["mlp"], h2)
+    with jax.named_scope("mlp"):
+        if kind == "attn_moe":
+            y2, _ = MOE.moe_apply(p["moe"], cfg, h2)
+        else:
+            y2 = L.mlp_apply(p["mlp"], h2)
     return x + y2, new_cache
 
 
@@ -350,14 +355,15 @@ class LM:
     def _logits(self, params, h):
         cfg = self.cfg
         head = (params["embed"].T if cfg.tie_embeddings else params["lm_head"])
-        logits = jnp.einsum("...d,dv->...v", h, head,
-                            preferred_element_type=jnp.float32)
-        logits = constrain(logits, *(["dp"] + [None] * (logits.ndim - 2)
-                                     + ["model"]))
-        if cfg.padded_vocab != cfg.vocab_size:   # mask the padding tail
-            pad_mask = jnp.arange(cfg.padded_vocab) < cfg.vocab_size
-            logits = jnp.where(pad_mask, logits, -1e30)
-        return L.softcap(logits, cfg.logits_softcap)
+        with jax.named_scope("head"):
+            logits = jnp.einsum("...d,dv->...v", h, head,
+                                preferred_element_type=jnp.float32)
+            logits = constrain(logits, *(["dp"] + [None] * (logits.ndim - 2)
+                                         + ["model"]))
+            if cfg.padded_vocab != cfg.vocab_size:   # mask the padding tail
+                pad_mask = jnp.arange(cfg.padded_vocab) < cfg.vocab_size
+                logits = jnp.where(pad_mask, logits, -1e30)
+            return L.softcap(logits, cfg.logits_softcap)
 
     # -- encoder ----------------------------------------------------------
     def _encode(self, params, frames):

@@ -45,6 +45,7 @@ import numpy as np
 from repro.models.config import ModelConfig
 from repro.models.transformer import LM
 from repro.models import kvcache as KV
+from repro.obs import span
 
 #: smallest prefill bucket — below this the compile is cheap enough that
 #: further splitting buys nothing
@@ -174,11 +175,10 @@ class InferenceEngine:
         self._pos_dirty = False
         self.buckets = prefill_buckets(max_len)
         self._compiled_buckets: set = set()
-        self._prefill = jax.jit(
-            lambda p, b: self.lm.prefill(p, b, self.max_len))
-        self._prefill_adapter = jax.jit(
-            lambda p, b, a1, b1: self.lm.prefill(p, b, self.max_len,
-                                                 adapter=(a1, b1)))
+        #: (chunk size, adapter route) of the fused decode programs
+        #: dispatched so far: the first dispatch of each compiles
+        self._compiled_chunks: set = set()
+        self._prefill = jax.jit(self._prefill_impl)
         # K-step fused decode: cache is DONATED — the scan updates it in
         # place instead of double-buffering the whole KV cache
         self._decode_fused = jax.jit(self._fused_impl, static_argnums=(4,),
@@ -620,18 +620,28 @@ class InferenceEngine:
                 f"prompt of {n} tokens exceeds engine max_len "
                 f"{self.max_len} for {session_id}")
         width = self._bucket(n)
+        first = width not in self._compiled_buckets
+        with span("engine.prefill", sid=session_id, tokens=n, bucket=width,
+                  first=int(first)):
+            tok = self._prefill_install(session_id, prompt, width, aidx,
+                                        adapter_id)
+        return {"first_token": tok,
+                "ttfb_ms": (time.perf_counter() - t0) * 1e3}
+
+    def _prefill_install(self, session_id: str, prompt: np.ndarray,
+                         width: int, aidx: int, adapter_id: str) -> int:
+        """Prefill ``prompt`` padded to ``width`` and install its cache in
+        a free slot; returns the first generated token."""
+        n = len(prompt)
         padded = np.zeros(width, np.int32)
         padded[:n] = prompt
         self._compiled_buckets.add(width)
         batch = {"tokens": jnp.asarray(padded[None, :], jnp.int32),
                  "length": jnp.int32(n)}
-        if aidx:
-            logits, cache1 = self._prefill_adapter(
-                self.params, batch, self.adapters.A[aidx],
-                self.adapters.B[aidx])
-        else:
-            logits, cache1 = self._prefill(self.params, batch)
-        tok = int(jnp.argmax(logits[0]))
+        lora = (self.adapters.A[aidx], self.adapters.B[aidx]) if aidx else ()
+        logits, cache1 = self._prefill(self.params, batch, *lora)
+        with span("engine.prefill.sync"):
+            tok = int(jnp.argmax(logits[0]))
         idx = self._alloc(session_id)
         meta = SlotState(session_id, position=n, tokens_generated=1,
                          last_token=tok, adapter_id=adapter_id,
@@ -649,15 +659,25 @@ class InferenceEngine:
                 raise
             row = np.zeros(self.pages_per_slot, np.int32)
             row[:len(meta.pages)] = meta.pages
-            self.cache = self._paged_install(
-                self.cache, cache1["layers"]["k"], cache1["layers"]["v"],
-                jnp.int32(idx), jnp.asarray(row), jnp.int32(n))
+            with span("engine.slot_install"):
+                self.cache = self._paged_install(
+                    self.cache, cache1["layers"]["k"],
+                    cache1["layers"]["v"], jnp.int32(idx),
+                    jnp.asarray(row), jnp.int32(n))
         else:
-            self._write_slot(idx, cache1)
-        return {"first_token": tok,
-                "ttfb_ms": (time.perf_counter() - t0) * 1e3}
+            with span("engine.slot_install"):
+                self._write_slot(idx, cache1)
+        return tok
 
     # ------------------------------------------------------------------
+    def _prefill_impl(self, params, batch, a1=None, b1=None):
+        """Prefill of one padded prompt; ``a1``/``b1``: the session's LoRA
+        rows, or None for the base model."""
+        with jax.named_scope("prefill"):
+            return self.lm.prefill(
+                params, batch, self.max_len,
+                adapter=None if a1 is None else (a1, b1))
+
     def _fused_impl(self, params, cache, last, active, steps: int):
         """K decode steps in one jitted scan. ``last``: [slots] int32 token
         feedback; ``active``: [slots] bool — inactive slots keep feeding
@@ -666,8 +686,9 @@ class InferenceEngine:
         Returns (cache, token block [slots, K])."""
         def step(carry, _):
             c, fed = carry
-            logits, c = self.lm.decode_step(params, c, fed[:, None],
-                                            active=active)
+            with jax.named_scope("decode_step"):
+                logits, c = self.lm.decode_step(params, c, fed[:, None],
+                                                active=active)
             nxt = jnp.argmax(logits[:, 0, :], axis=-1).astype(jnp.int32)
             fed = jnp.where(active, nxt, fed)
             return (c, fed), fed
@@ -685,9 +706,10 @@ class InferenceEngine:
         contents change."""
         def step(carry, _):
             c, fed = carry
-            logits, c = self.lm.decode_step(
-                params, c, fed[:, None], active=active,
-                adapter=(adp_a, adp_b, aidx, route))
+            with jax.named_scope("decode_step"):
+                logits, c = self.lm.decode_step(
+                    params, c, fed[:, None], active=active,
+                    adapter=(adp_a, adp_b, aidx, route))
             nxt = jnp.argmax(logits[:, 0, :], axis=-1).astype(jnp.int32)
             fed = jnp.where(active, nxt, fed)
             return (c, fed), fed
@@ -917,9 +939,49 @@ class InferenceEngine:
         ``steps=K``    — fused K-step chunk: {session: [token, ...] * K},
         produced by ONE dispatch and ONE device→host transfer.
         """
-        if not self._slot_map:
+        if not any(s is not None and not s.parked for s in self._slots):
             return {}
         k = 1 if steps is None else max(1, int(steps))
+        route = None if self.adapters is None else self.adapters.route
+        first = (k, route) not in self._compiled_chunks
+        with span("engine.decode", steps=k, first=int(first)):
+            out = self._decode_chunk(k, steps is None)
+        self._compiled_chunks.add((k, route))
+        return out
+
+    def _decode_chunk(self, k: int, single: bool
+                      ) -> Dict[str, Union[int, List[int]]]:
+        with span("engine.decode.inputs"):
+            last, active = self._decode_inputs(k)
+        if self.adapters is not None:
+            aidx = np.zeros(self.slots, np.int32)
+            for i, s in enumerate(self._slots):
+                if s is not None and s.adapter_id:
+                    aidx[i] = self.adapters.index_of(s.adapter_id)
+            self.cache, block = self._decode_fused_adp(
+                self.params, self.cache, last, active, jnp.asarray(aidx),
+                self.adapters.A, self.adapters.B, k, self.adapters.route)
+        else:
+            self.cache, block = self._decode_fused(
+                self.params, self.cache, last, active, k)
+        with span("engine.decode.wait"):
+            block = np.asarray(block)                    # [slots, K]
+        out: Dict[str, Union[int, List[int]]] = {}
+        for i, s in enumerate(self._slots):
+            if s is None or s.parked:
+                continue
+            s.last_token = int(block[i, -1])
+            s.position += k
+            s.tokens_generated += k
+            s.last_used = next(self._use_clock)
+            out[s.session_id] = (int(block[i, 0]) if single
+                                 else [int(t) for t in block[i]])
+        return out
+
+    def _decode_inputs(self, k: int):
+        """The chunk's device inputs: each slot's last token and whether it
+        advances, after growing the block tables and resyncing the device
+        positions where those may have drifted from the host's."""
         last = np.zeros(self.slots, np.int32)
         active = np.zeros(self.slots, bool)
         any_parked = False
@@ -931,8 +993,6 @@ class InferenceEngine:
                 continue
             last[i] = s.last_token
             active[i] = True
-        if not active.any():
-            return {}
         if self.paged:
             # grow block tables BEFORE the fused chunk — the scan cannot
             # allocate mid-flight; under pressure this hibernates coldest
@@ -954,31 +1014,7 @@ class InferenceEngine:
                 cache["block"] = jnp.asarray(self._block_host)
             self.cache = cache
             self._pos_dirty = any_parked
-        if self.adapters is not None:
-            aidx = np.zeros(self.slots, np.int32)
-            for i, s in enumerate(self._slots):
-                if s is not None and s.adapter_id:
-                    aidx[i] = self.adapters.index_of(s.adapter_id)
-            self.cache, block = self._decode_fused_adp(
-                self.params, self.cache, jnp.asarray(last),
-                jnp.asarray(active), jnp.asarray(aidx),
-                self.adapters.A, self.adapters.B, k, self.adapters.route)
-        else:
-            self.cache, block = self._decode_fused(
-                self.params, self.cache, jnp.asarray(last),
-                jnp.asarray(active), k)
-        block = np.asarray(block)                        # [slots, K]
-        out: Dict[str, Union[int, List[int]]] = {}
-        for i, s in enumerate(self._slots):
-            if s is None or s.parked:
-                continue
-            s.last_token = int(block[i, -1])
-            s.position += k
-            s.tokens_generated += k
-            s.last_used = next(self._use_clock)
-            out[s.session_id] = (int(block[i, 0]) if steps is None
-                                 else [int(t) for t in block[i]])
-        return out
+        return jnp.asarray(last), jnp.asarray(active)
 
     # ------------------------------------------------------------------
     def serve(self, session_id: str, prompt_tokens: int, gen_tokens: int,

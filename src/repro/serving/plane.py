@@ -43,6 +43,7 @@ import numpy as np
 
 from repro.core.clock import Clock, VirtualClock
 from repro.core.failures import FailureCause
+from repro.obs import span
 from repro.serving.engine import PagePoolExhausted, RequestRefused
 from repro.serving.scheduler import QoSScheduler, Request
 
@@ -530,44 +531,49 @@ class ServingPlane:
             predicted_service_ms=self.backend.predicted_service_ms,
             skip=self._skip, on_fast_fail=self._fast_fail)
         for req in batch:
-            # the admitting request's own session must never be the reclaim
-            # victim — a resume=True request's parked state is exactly what
-            # it is about to continue
-            self.backend.ensure_capacity(
-                self._active_sessions | {req.session_id})
-            try:
-                adm = self.backend.admit(req, self.clock.now())
-            except (RequestRefused, PagePoolExhausted) as e:
-                # the request is already in scheduler.running — a designed
-                # refusal (infeasible request, no KV pages) must free that
-                # slot and surface as a failed result, never wedge the site.
-                # Anything else (runtime, compiler, device memory) is a
-                # fault of the site, not an answer to the request: it
-                # propagates instead of posing as a busy site
-                self.scheduler.detach(req.request_id)
-                cause = (FailureCause.NO_FEASIBLE_BINDING
-                         if isinstance(e, RequestRefused)
-                         else FailureCause.COMPUTE_SCARCITY)
-                self._finish(req, ttfb_ms=0.0, completed=False, failed=cause,
-                             detail=f"{type(e).__name__}: {e}")
-                continue
-            self._active_sessions.add(req.session_id)
-            req.hint_ttfb_ms = adm.ttfb_ms            # measured/known TTFB
-            if adm.finish_at is not None:
-                # event-driven backend: the whole generation completes at
-                # finish_at, so the token budget is accounted up front
-                self._tokens[req.request_id] = req.gen_tokens
-                heapq.heappush(self._events,
-                               (adm.finish_at, next(self._seq), req))
-            elif adm.resumed:
-                # no prefill ran: generation continues from the bound
-                # state at the next decode round
-                self._tokens[req.request_id] = 0
-                self._tok_ids[req.request_id] = []
-            else:
-                self._tokens[req.request_id] = 1      # prefill's first token
-                if adm.first_token is not None:
-                    self._tok_ids[req.request_id] = [adm.first_token]
+            with span("plane.admit", rid=req.request_id,
+                      sid=req.session_id):
+                self._admit_one(req)
+
+    def _admit_one(self, req: Request) -> None:
+        # the admitting request's own session must never be the reclaim
+        # victim — a resume=True request's parked state is exactly what
+        # it is about to continue
+        self.backend.ensure_capacity(
+            self._active_sessions | {req.session_id})
+        try:
+            adm = self.backend.admit(req, self.clock.now())
+        except (RequestRefused, PagePoolExhausted) as e:
+            # the request is already in scheduler.running — a designed
+            # refusal (infeasible request, no KV pages) must free that
+            # slot and surface as a failed result, never wedge the site.
+            # Anything else (runtime, compiler, device memory) is a
+            # fault of the site, not an answer to the request: it
+            # propagates instead of posing as a busy site
+            self.scheduler.detach(req.request_id)
+            cause = (FailureCause.NO_FEASIBLE_BINDING
+                     if isinstance(e, RequestRefused)
+                     else FailureCause.COMPUTE_SCARCITY)
+            self._finish(req, ttfb_ms=0.0, completed=False, failed=cause,
+                         detail=f"{type(e).__name__}: {e}")
+            return
+        self._active_sessions.add(req.session_id)
+        req.hint_ttfb_ms = adm.ttfb_ms            # measured/known TTFB
+        if adm.finish_at is not None:
+            # event-driven backend: the whole generation completes at
+            # finish_at, so the token budget is accounted up front
+            self._tokens[req.request_id] = req.gen_tokens
+            heapq.heappush(self._events,
+                           (adm.finish_at, next(self._seq), req))
+        elif adm.resumed:
+            # no prefill ran: generation continues from the bound
+            # state at the next decode round
+            self._tokens[req.request_id] = 0
+            self._tok_ids[req.request_id] = []
+        else:
+            self._tokens[req.request_id] = 1      # prefill's first token
+            if adm.first_token is not None:
+                self._tok_ids[req.request_id] = [adm.first_token]
 
     def _finish(self, req: Request, *, ttfb_ms: float, completed: bool,
                 failed: Optional[FailureCause] = None,
@@ -589,13 +595,14 @@ class ServingPlane:
         self._by_request.pop(req.request_id, None)
 
     def _complete(self, req: Request) -> None:
-        self.scheduler.complete(req.request_id)
-        self.backend.release(req.session_id)
-        self._active_sessions.discard(req.session_id)
-        latency_ms = (self.clock.now() - req.submitted_at) * 1e3
-        self._finish(req, ttfb_ms=req.hint_ttfb_ms or 0.0,
-                     completed=latency_ms <= req.t_max_ms)
-        self._admit()               # freed slot: admit from the queue
+        with span("plane.complete", rid=req.request_id):
+            self.scheduler.complete(req.request_id)
+            self.backend.release(req.session_id)
+            self._active_sessions.discard(req.session_id)
+            latency_ms = (self.clock.now() - req.submitted_at) * 1e3
+            self._finish(req, ttfb_ms=req.hint_ttfb_ms or 0.0,
+                         completed=latency_ms <= req.t_max_ms)
+            self._admit()               # freed slot: admit from the queue
 
     def _chunk_steps(self) -> int:
         """Fused-decode chunk size for the next round: bounded by (a) the
@@ -628,22 +635,23 @@ class ServingPlane:
         if not self.scheduler.running:
             return False
         steps = self._chunk_steps()
-        out = self.backend.decode_round(steps=steps)
-        if not out:
-            return False
-        finished = []
-        for req in list(self.scheduler.running.values()):
-            if req.session_id in out:
-                block = out[req.session_id]
-                self._tokens[req.request_id] = \
-                    self._tokens.get(req.request_id, 0) + len(block)
-                if req.request_id in self._tok_ids:
-                    self._tok_ids[req.request_id].extend(block)
-                if self._tokens[req.request_id] >= req.gen_tokens:
-                    finished.append(req)
-        for req in finished:
-            self._complete(req)
-        return True
+        with span("plane.chunk", steps=steps):
+            out = self.backend.decode_round(steps=steps)
+            if not out:
+                return False
+            finished = []
+            for req in list(self.scheduler.running.values()):
+                if req.session_id in out:
+                    block = out[req.session_id]
+                    self._tokens[req.request_id] = \
+                        self._tokens.get(req.request_id, 0) + len(block)
+                    if req.request_id in self._tok_ids:
+                        self._tok_ids[req.request_id].extend(block)
+                    if self._tokens[req.request_id] >= req.gen_tokens:
+                        finished.append(req)
+            for req in finished:
+                self._complete(req)
+            return True
 
     # ------------------------------------------------------------------
     # make-before-break handover (migration data plane)

@@ -22,6 +22,7 @@ from repro.api.gateway import NorthboundGateway
 from repro.core.catalog import Catalog
 from repro.core.orchestrator import Orchestrator
 from repro.core.session import AISession
+from repro.obs import install_gc_spans
 from repro.serving.engine import InferenceEngine
 from repro.serving.plane import (RealEngineBackend, ServingPlane,
                                  PlaneResult)
@@ -60,6 +61,7 @@ class AIaaSServer:
                  decode_chunk: Optional[Dict[str, int]] = None,
                  pallas_decode: bool = False):
         self.orch = orch
+        install_gc_spans()
         self.fleet = EngineFleet(orch.catalog, model_id, slots=slots,
                                  max_len=max_len, pallas_decode=pallas_decode)
         self.planes: Dict[str, ServingPlane] = {}
