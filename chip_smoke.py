@@ -237,7 +237,10 @@ def phase_kernels(label, *, B=8, HQ=32, HKV=8, D=128, S=2048, page=128,
         ref_paged = jax.jit(paged_decode_attention_ref)(
             q, pool_k, pool_v, lengths, tables)
     cases = {
-        "decode_attention": (ops.decode(q, k, v, lengths), ref),
+        # the kernel reads the stacked cache [L, B, S, Hkv, D]: one layer
+        "decode_attention": (ops.decode(q, jnp.moveaxis(k, 1, 2)[None],
+                                        jnp.moveaxis(v, 1, 2)[None],
+                                        lengths, 0), ref),
         "paged_decode_attention": (
             ops.paged_decode(q, pool_k, pool_v, lengths, tables),
             ref_paged),

@@ -15,8 +15,11 @@ def _on_tpu() -> bool:
 
 
 @functools.partial(jax.jit, static_argnames=("block_kv",))
-def decode(q, k, v, lengths, *, block_kv: int = 512):
-    return decode_attention(q, k, v, lengths, block_kv=block_kv,
+def decode(q, k, v, lengths, layer=0, *, block_kv: int | None = None):
+    """q: [B, Hq, D]; k/v: the stacked cache [L, B, S, Hkv, D]; lengths:
+    [B]; layer: the stack's layer to attend -> [B, Hq, D]. One layer's
+    buffer [B, S, Hkv, D] is passed as ``k[None]`` with layer 0."""
+    return decode_attention(q, k, v, lengths, layer, block_kv=block_kv,
                             interpret=not _on_tpu())
 
 

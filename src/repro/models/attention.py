@@ -336,7 +336,7 @@ def cross_attention(p, cfg: ModelConfig, x, memory, mem_positions):
 
 
 def decode_self_attention(p, cfg: ModelConfig, x, cache_k, cache_v, position,
-                          *, window: int = 0, active=None):
+                          *, window: int = 0, active=None, layer=None):
     """Single-token decode against a KV cache ring/linear buffer.
 
     x: [b, 1, d]; cache_k/v: [b, S, kh, hd]; position: [b] int32 — the
@@ -344,6 +344,11 @@ def decode_self_attention(p, cfg: ModelConfig, x, cache_k, cache_v, position,
     continuous batching: sessions in the same decode batch sit at different
     offsets). For sliding-window caches the buffer is a ring of size
     ``window`` indexed modulo.
+
+    With ``layer`` (int32 scalar), cache_k/v are the whole layer stacks
+    [L, b, S, kh, hd]: the new row is written into layer ``layer`` of the
+    stack, the Pallas kernel then reads the updated stack in place, and the
+    stacks are returned. Without it the layer's own buffers are returned.
 
     ``active`` ([b] bool, optional) suppresses the cache write for inactive
     rows: parked (idle-resident) sessions ride the fused batch without their
@@ -357,12 +362,29 @@ def decode_self_attention(p, cfg: ModelConfig, x, cache_k, cache_v, position,
     with jax.named_scope("qkv"):
         q = _project_q(p, cfg, x, position[:, None])
         k_new, v_new = _project_kv(p, cfg, x, position[:, None])
+    stacked = layer is not None
+    if not stacked:                       # one layer's buffers: a stack of 1
+        cache_k, cache_v, layer = cache_k[None], cache_v[None], 0
     with jax.named_scope("kv_write"):
-        cache_k, cache_v = _decode_cache_write(
-            cfg, cache_k, cache_v, k_new, v_new, position, window, active)
+        k_layer, v_layer = _decode_cache_write(
+            cfg, jax.lax.dynamic_index_in_dim(cache_k, layer, 0, False),
+            jax.lax.dynamic_index_in_dim(cache_v, layer, 0, False),
+            k_new, v_new, position, window, active)
+        cache_k = jax.lax.dynamic_update_index_in_dim(
+            cache_k, k_layer.astype(cache_k.dtype), layer, 0)
+        cache_v = jax.lax.dynamic_update_index_in_dim(
+            cache_v, v_layer.astype(cache_v.dtype), layer, 0)
     with jax.named_scope("attn"):
-        return _decode_attend(p, cfg, x, q, cache_k, cache_v, position,
-                              window)
+        if cfg.use_pallas_decode and not window \
+                and not cfg.attn_logits_softcap:
+            out = _decode_attend_kernel(p, cfg, x, q, cache_k, cache_v,
+                                        layer, position)
+        else:
+            out = _decode_attend(p, cfg, x, q, k_layer, v_layer, position,
+                                 window)
+    if not stacked:
+        return out, cache_k[0], cache_v[0]
+    return out, cache_k, cache_v
 
 
 def _decode_cache_write(cfg: ModelConfig, cache_k, cache_v, k_new, v_new,
@@ -393,36 +415,36 @@ def _decode_cache_write(cfg: ModelConfig, cache_k, cache_v, k_new, v_new,
     return cache_k, cache_v
 
 
+def _decode_attend_kernel(p, cfg: ModelConfig, x, q, stack_k, stack_v, layer,
+                          position):
+    """The flash-decode Pallas kernel over layer ``layer`` of the written
+    stack [L, b, S, kh, hd], through the output projection. Linear buffer
+    only: the slot index IS the absolute position, so the kernel's
+    ``kpos < length`` ragged mask is exactly the reference path's
+    ``kpos <= position``; ring buffers and softcapped logits stay on the
+    reference path."""
+    from repro.kernels.decode_attention.decode_attention import \
+        decode_attention
+    b, S = x.shape[0], stack_k.shape[2]
+    o = decode_attention(
+        q[:, 0], stack_k, stack_v,                      # [b, hq, hd]
+        # clamp at the buffer: past position S-1 the linear cache holds
+        # exactly S valid rows (the reference mask is slot <= position
+        # over slots [0, S))
+        jnp.minimum(position + 1, S), layer,
+        interpret=jax.default_backend() != "tpu")
+    o = o.reshape(b, 1, cfg.q_dim).astype(x.dtype)
+    return jnp.einsum("bsq,qd->bsd", o, as_weight(p["w_o"]),
+                      preferred_element_type=jnp.float32).astype(x.dtype)
+
+
 def _decode_attend(p, cfg: ModelConfig, x, q, cache_k, cache_v, position,
                    window):
-    """The new token's attention over the written buffer, through the
-    output projection."""
+    """The reference path: the new token's attention over the layer's
+    written buffer, through the output projection."""
     b, S = x.shape[0], cache_k.shape[1]
-    kh, hd, hq = cfg.num_kv_heads, cfg.head_dim, cfg.num_heads
-    g = hq // kh
-    if cfg.use_pallas_decode and not window and not cfg.attn_logits_softcap:
-        # flash-decode Pallas kernel: linear buffer only (slot index IS the
-        # absolute position, so the kernel's `kpos < length` ragged mask is
-        # exactly the reference path's `kpos <= position`); ring buffers and
-        # softcapped logits stay on the reference path
-        from repro.kernels.decode_attention.decode_attention import \
-            decode_attention
-        o = decode_attention(
-            q[:, 0],                                    # [b, hq, hd]
-            jnp.moveaxis(cache_k, 1, 2),                # [b, kh, S, hd]
-            jnp.moveaxis(cache_v, 1, 2),
-            # clamp at the buffer: past position S-1 the linear cache holds
-            # exactly S valid rows (the reference mask is slot <= position
-            # over slots [0, S)); unclamped, zero-padded rows added by the
-            # kernel's block_kv rounding would pass its kpos < length mask
-            jnp.minimum(position + 1, S),
-            block_kv=min(512, -(-S // 128) * 128),
-            interpret=jax.default_backend() != "tpu")
-        o = o.reshape(b, 1, cfg.q_dim).astype(x.dtype)
-        out = jnp.einsum("bsq,qd->bsd", o, as_weight(p["w_o"]),
-                         preferred_element_type=jnp.float32).astype(x.dtype)
-        return out, cache_k, cache_v
-
+    kh, hd = cfg.num_kv_heads, cfg.head_dim
+    g = cfg.num_heads // kh
     # absolute position of every cache slot, per row: [b, S]
     idx = jnp.arange(S, dtype=jnp.int32)
     if window:
@@ -443,9 +465,8 @@ def _decode_attend(p, cfg: ModelConfig, x, q, cache_k, cache_v, position,
     o = jnp.einsum("bhgqk,bkhd->bhgqd", w.astype(cache_v.dtype), cache_v,
                    preferred_element_type=jnp.float32)
     o = jnp.moveaxis(o, 3, 1).reshape(b, 1, cfg.q_dim).astype(x.dtype)
-    out = jnp.einsum("bsq,qd->bsd", o, as_weight(p["w_o"]),
-                     preferred_element_type=jnp.float32).astype(x.dtype)
-    return out, cache_k, cache_v
+    return jnp.einsum("bsq,qd->bsd", o, as_weight(p["w_o"]),
+                      preferred_element_type=jnp.float32).astype(x.dtype)
 
 
 def paged_decode_self_attention(p, cfg: ModelConfig, x, k_pages, v_pages,

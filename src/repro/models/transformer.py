@@ -205,11 +205,14 @@ def _keep_active(new, old, active):
 
 
 def _block_decode(p, cfg: ModelConfig, kind: str, x, cache_layer, position,
-                  active=None, block=None):
+                  active=None, block=None, layer=None):
     """Single-token block. Returns (x, new_cache_layer).
 
     ``active`` ([b] bool) masks state updates of inactive rows; ``block``
-    ([b, PPS] int32) routes attention K/V through the paged pool layout.
+    ([b, PPS] int32) routes attention K/V through the paged pool layout;
+    ``layer`` (int32 scalar) marks ``cache_layer``'s "k"/"v" as the whole
+    layer stacks, written and read in place at that layer (see
+    ``attention.decode_self_attention``) and returned whole.
     """
     x = constrain(x, "dp", None, None)
     h = L.rmsnorm_apply(p["norm1"], x, cfg.norm_eps)
@@ -235,7 +238,8 @@ def _block_decode(p, cfg: ModelConfig, kind: str, x, cache_layer, position,
         y, ck, cv = A.decode_self_attention(p["attn"], cfg, h,
                                             cache_layer["k"],
                                             cache_layer["v"], position,
-                                            window=window, active=active)
+                                            window=window, active=active,
+                                            layer=layer)
     x = x + y
     new_cache = dict(cache_layer)
     new_cache["k"], new_cache["v"] = ck, cv
@@ -614,15 +618,22 @@ class LM:
                 layer_cache["cross_k"] = cache["cross_k"]
                 layer_cache["cross_v"] = cache["cross_v"]
             L_layers = cfg.num_layers
+            # dense K/V stay whole: the attention writes the layer's row
+            # into the stack and its kernel reads the stack in place, so
+            # no layer slice is handed to it (a custom call's operand is a
+            # buffer of its own: a slice would be copied every layer)
+            whole = () if block is not None or kind == "ssm" else ("k", "v")
 
             def body(carry, xs):
                 h, cstack = carry
                 lp, idx = xs
-                cl = jax.tree.map(
-                    lambda c: jax.lax.dynamic_index_in_dim(
-                        c, idx, axis=0, keepdims=False), cstack)
+                cl = {key: c if key in whole else
+                      jax.lax.dynamic_index_in_dim(c, idx, axis=0,
+                                                   keepdims=False)
+                      for key, c in cstack.items()}
                 h, ncl = _block_decode(lp, cfg, kind, h, cl, position,
-                                       active=active, block=block)
+                                       active=active, block=block,
+                                       layer=idx if whole else None)
                 # write back only the mutated leaves (cross K/V are static)
                 def upd(c, n):
                     return jax.lax.dynamic_update_index_in_dim(
@@ -630,7 +641,8 @@ class LM:
                 new_stack = dict(cstack)
                 for key in ("k", "v", "conv", "ssm"):
                     if key in ncl and key in cstack:
-                        new_stack[key] = upd(cstack[key], ncl[key])
+                        new_stack[key] = (ncl[key] if key in whole
+                                          else upd(cstack[key], ncl[key]))
                 return (h, new_stack), None
 
             (x, stacked), _ = jax.lax.scan(

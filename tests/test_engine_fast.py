@@ -212,9 +212,10 @@ class TestPallasDecodeRoute:
 
     def test_decode_past_buffer_stays_on_reference_mask(self):
         """Positions >= S (generation past the cache buffer): the kernel's
-        ragged-length mask must clamp at S — unclamped it would admit the
-        zero-padded KV rows the kernel's block_kv rounding appends, which
-        showed up as ~0.15 max divergence vs the ~3e-3 bf16 noise floor."""
+        ragged-length mask must clamp at S — unclamped it would admit rows
+        past the buffer (a partial last kv block reads them; when the
+        kernel padded the buffer this showed up as ~0.15 max divergence vs
+        the ~3e-3 bf16 noise floor)."""
         import jax
         import jax.numpy as jnp
         from repro.models import attention as A
@@ -239,6 +240,44 @@ class TestPallasDecodeRoute:
             np.testing.assert_allclose(
                 np.asarray(o_ref, np.float32), np.asarray(o_pal, np.float32),
                 atol=2e-2, rtol=2e-2)
+
+    @pytest.mark.parametrize("pallas", [False, True],
+                             ids=["reference", "pallas"])
+    def test_stacked_cache_matches_layer_buffer(self, pallas):
+        """Given the whole layer stack and a layer index, decode attention
+        writes that layer's row in place and attends it exactly as it does
+        the layer's own buffer; the other layers stay bit-identical."""
+        import jax
+        import jax.numpy as jnp
+        from repro.models import attention as A
+
+        cfg = dataclasses.replace(get_config("edge-tiny"),
+                                  use_pallas_decode=pallas)
+        p = A.attention_init(jax.random.key(0), cfg)
+        x = jax.random.normal(jax.random.key(1), (2, 1, cfg.d_model),
+                              jnp.float32).astype(jnp.bfloat16)
+        shape = (3, 2, 40, cfg.num_kv_heads, cfg.head_dim)
+        sk = jax.random.normal(jax.random.key(2), shape,
+                               jnp.float32).astype(jnp.bfloat16)
+        sv = jax.random.normal(jax.random.key(3), shape,
+                               jnp.float32).astype(jnp.bfloat16)
+        pos = jnp.array([7, 39], jnp.int32)
+        active = jnp.array([True, False])
+        for layer in (0, 2):
+            o_one, ck, cv = A.decode_self_attention(
+                p, cfg, x, sk[layer], sv[layer], pos, active=active)
+            o_st, nk, nv = A.decode_self_attention(
+                p, cfg, x, sk, sv, pos, active=active,
+                layer=jnp.int32(layer))
+            np.testing.assert_array_equal(np.asarray(o_one, np.float32),
+                                          np.asarray(o_st, np.float32))
+            np.testing.assert_array_equal(np.asarray(nk[layer]),
+                                          np.asarray(ck))
+            np.testing.assert_array_equal(np.asarray(nv[layer]),
+                                          np.asarray(cv))
+            others = [i for i in range(3) if i != layer]
+            np.testing.assert_array_equal(np.asarray(nk)[others],
+                                          np.asarray(sk)[others])
 
     def test_window_and_softcap_fall_back_to_reference(self):
         """The kernel only implements linear buffers without softcap; the

@@ -3,6 +3,8 @@
 Shapes/dtypes swept per the assignment; hypothesis drives extra ragged
 shapes for the decode kernel (continuous batching is shape-irregular)."""
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -12,7 +14,7 @@ from hypothesis import given, settings, strategies as st
 from repro.kernels.flash_attention.flash_attention import flash_attention
 from repro.kernels.flash_attention.ref import attention_ref
 from repro.kernels.decode_attention.decode_attention import (
-    decode_attention, paged_decode_attention)
+    decode_attention, kv_block, paged_decode_attention)
 from repro.kernels.decode_attention.ref import (decode_attention_ref,
                                                 paged_decode_attention_ref)
 from repro.kernels.rglru_scan.rglru_scan import rglru_scan
@@ -50,6 +52,19 @@ class TestFlashAttention:
         assert err < tol(dt), err
 
 
+def _stacked(k, v, *, layers=1, layer=0, seed=0):
+    """The kernel's operand built from [B, Hkv, S, D] data: a stack
+    [layers, B, S, Hkv, D] (``models.kvcache``'s layout) holding ``k``/``v``
+    at ``layer`` and other data in every other layer."""
+    kl, vl = jnp.moveaxis(k, 1, 2), jnp.moveaxis(v, 1, 2)
+    ks = jax.random.split(jax.random.key(seed), 2)
+    shape = (layers,) + kl.shape
+    other_k = 100.0 * jax.random.normal(ks[0], shape, jnp.float32)
+    other_v = -100.0 * jax.random.normal(ks[1], shape, jnp.float32)
+    return (other_k.astype(k.dtype).at[layer].set(kl),
+            other_v.astype(v.dtype).at[layer].set(vl))
+
+
 class TestDecodeAttention:
     @pytest.mark.parametrize("B,Hq,Hkv,S,D,dt", [
         (4, 8, 2, 1024, 64, jnp.float32),
@@ -62,7 +77,8 @@ class TestDecodeAttention:
         k = jax.random.normal(ks[1], (B, Hkv, S, D), jnp.float32).astype(dt)
         v = jax.random.normal(ks[2], (B, Hkv, S, D), jnp.float32).astype(dt)
         lengths = jax.random.randint(ks[3], (B,), 1, S + 1)
-        out = decode_attention(q, k, v, lengths, interpret=True)
+        sk, sv = _stacked(k, v)
+        out = decode_attention(q, sk, sv, lengths, 0, interpret=True)
         ref = decode_attention_ref(q, k, v, lengths)
         err = float(jnp.max(jnp.abs(out.astype(jnp.float32)
                                     - ref.astype(jnp.float32))))
@@ -79,10 +95,79 @@ class TestDecodeAttention:
         k = jax.random.normal(ks[1], (B, Hkv, S, D), jnp.float32)
         v = jax.random.normal(ks[2], (B, Hkv, S, D), jnp.float32)
         lengths = jax.random.randint(ks[3], (B,), 1, S + 1)
-        out = decode_attention(q, k, v, lengths, block_kv=64, interpret=True)
+        sk, sv = _stacked(k, v)
+        out = decode_attention(q, sk, sv, lengths, 0, block_kv=64,
+                               interpret=True)
         ref = decode_attention_ref(q, k, v, lengths)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    atol=5e-5, rtol=1e-4)
+
+    @pytest.mark.parametrize("layer", [0, 3], ids=["first", "last"])
+    @pytest.mark.parametrize("dt", [jnp.float32, jnp.bfloat16])
+    def test_reads_only_its_layer(self, layer, dt):
+        """Layer 0 and L-1 of a stack whose other layers hold other data:
+        the index map picks the layer, nothing of the others leaks in."""
+        B, Hq, Hkv, S, D = 3, 8, 2, 256, 64
+        ks = jax.random.split(jax.random.key(layer), 4)
+        q = jax.random.normal(ks[0], (B, Hq, D), jnp.float32).astype(dt)
+        k = jax.random.normal(ks[1], (B, Hkv, S, D), jnp.float32).astype(dt)
+        v = jax.random.normal(ks[2], (B, Hkv, S, D), jnp.float32).astype(dt)
+        lengths = jax.random.randint(ks[3], (B,), 1, S + 1)
+        sk, sv = _stacked(k, v, layers=4, layer=layer, seed=layer + 1)
+        out = decode_attention(q, sk, sv, lengths, jnp.int32(layer),
+                               block_kv=128, interpret=True)
+        ref = decode_attention_ref(q, k, v, lengths)
+        err = float(jnp.max(jnp.abs(out.astype(jnp.float32)
+                                    - ref.astype(jnp.float32))))
+        assert err < tol(dt), err
+
+    @pytest.mark.parametrize("lengths", [(1, 1, 1), (512, 512, 512),
+                                         (1, 257, 512), (512, 1, 128)])
+    def test_ragged_lengths_edges(self, lengths):
+        """One-token rows and full rows (length S) side by side."""
+        B, Hq, Hkv, S, D = 3, 8, 2, 512, 64
+        ks = jax.random.split(jax.random.key(sum(lengths)), 3)
+        q = jax.random.normal(ks[0], (B, Hq, D), jnp.float32)
+        k = jax.random.normal(ks[1], (B, Hkv, S, D), jnp.float32)
+        v = jax.random.normal(ks[2], (B, Hkv, S, D), jnp.float32)
+        n = jnp.asarray(lengths, jnp.int32)
+        sk, sv = _stacked(k, v, layers=2, layer=1)
+        out = decode_attention(q, sk, sv, n, 1, block_kv=128,
+                               interpret=True)
+        np.testing.assert_allclose(
+            np.asarray(out), np.asarray(decode_attention_ref(q, k, v, n)),
+            atol=5e-5, rtol=1e-4)
+
+    @pytest.mark.parametrize("S,block_kv", [(257, 128), (300, 128),
+                                            (300, None), (1000, None)])
+    def test_unaligned_buffer_is_not_padded(self, S, block_kv):
+        """S not a multiple of 128: a partial last block (257 and 300 at
+        128 rows) gives no NaN, and the stack reaches the kernel unpadded
+        (a pad would copy the whole cache)."""
+        B, Hq, Hkv, D = 2, 4, 2, 64
+        ks = jax.random.split(jax.random.key(S), 4)
+        q = jax.random.normal(ks[0], (B, Hq, D), jnp.float32)
+        k = jax.random.normal(ks[1], (B, Hkv, S, D), jnp.float32)
+        v = jax.random.normal(ks[2], (B, Hkv, S, D), jnp.float32)
+        n = jnp.asarray([S, 1 + S // 2], jnp.int32)
+        sk, sv = _stacked(k, v, layers=2, layer=1)
+        run = lambda sk, sv, n: decode_attention(q, sk, sv, n, 1,
+                                                 block_kv=block_kv,
+                                                 interpret=True)
+        jaxpr = str(jax.make_jaxpr(run)(sk, sv, n))
+        assert not re.search(r"\bpad\[", jaxpr), jaxpr
+        out = np.asarray(run(sk, sv, n))
+        assert np.isfinite(out).all()
+        np.testing.assert_allclose(
+            out, np.asarray(decode_attention_ref(q, k, v, n)),
+            atol=5e-5, rtol=1e-4)
+
+    @pytest.mark.parametrize("S,want", [(96, 96), (512, 512), (2048, 512),
+                                        (1000, 500), (2039, 512)])
+    def test_kv_block_divides_or_masks(self, S, want):
+        """The default block: whole buffer, a divisor near 512, or 512 with
+        a masked partial block when S has no divisor near it."""
+        assert kv_block(S) == want
 
 
 def _paged_case(seed, B, Hkv, S, D, page, *, extra_pages=3):

@@ -21,6 +21,7 @@ from repro.kernels.moe_gemm.moe_gemm import moe_gemm
 
 # minitron-8b decode widths: 8 slots, GQA 32/8 heads of 128, 2048 context
 B, HQ, HKV, D, S = 8, 32, 8, 128, 2048
+LAYERS = 4                        # the stacked cache's depth
 PAGE = 128                        # models.kvcache.DEFAULT_PAGE_SIZE
 PPS = S // PAGE
 D_MODEL, RANK, ADAPTERS = 4096, 8, 9   # AdapterRuntime: 8 tenants + null row
@@ -49,11 +50,62 @@ def _compiled_text(fn, *args) -> str:
 
 
 def test_decode_attention_compiles(spec):
+    stack = (LAYERS, B, S, HKV, D)              # models.kvcache layout
     text = _compiled_text(
-        lambda q, k, v, n: decode_attention(q, k, v, n, interpret=False),
-        spec((B, HQ, D), jnp.bfloat16), spec((B, HKV, S, D), jnp.bfloat16),
-        spec((B, HKV, S, D), jnp.bfloat16), spec((B,), jnp.int32))
+        lambda q, k, v, n, i: decode_attention(q, k, v, n, i,
+                                               interpret=False),
+        spec((B, HQ, D), jnp.bfloat16), spec(stack, jnp.bfloat16),
+        spec(stack, jnp.bfloat16), spec((B,), jnp.int32),
+        spec((), jnp.int32))
     assert "tpu_custom_call" in text
+
+
+def _outputs(text: str, op: str) -> list:
+    """The result types of every ``op`` instruction of an HLO text."""
+    return [line.split(" = ", 1)[1].split(f" {op}(", 1)[0]
+            for line in text.splitlines()
+            if f" {op}(" in line and " = " in line]
+
+
+def test_decode_step_reads_the_stacked_cache_in_place(spec, monkeypatch):
+    """The fused decode program of a dense model (the engine's jitted scan
+    of ``LM.decode_step``, cache donated) hands the Pallas kernel the
+    stacked cache itself: no copy of the whole stack (a relayout for a
+    kernel operand) and no fusion that outputs one layer's K or V."""
+    from repro.models.config import ModelConfig
+    from repro.models.transformer import LM
+
+    slots = 4                   # unlike HKV, so each layout has its shape
+    cfg = ModelConfig(name="dense-2L", family="dense", num_layers=2,
+                      d_model=256, num_heads=2 * HKV, num_kv_heads=HKV,
+                      head_dim=D, d_ff=512, vocab_size=512, remat="none",
+                      use_pallas_decode=True)
+    lm = LM(cfg)
+    # the call site picks interpret mode from the backend it sees
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def fused(params, cache, last, active):     # InferenceEngine._fused_impl
+        def step(carry, _):
+            c, fed = carry
+            logits, c = lm.decode_step(params, c, fed[:, None],
+                                       active=active)
+            nxt = jnp.argmax(logits[:, 0, :], axis=-1).astype(jnp.int32)
+            return (c, jnp.where(active, nxt, fed)), nxt
+        (cache, _), toks = jax.lax.scan(step, (cache, last), None, length=2)
+        return cache, toks
+
+    as_spec = lambda t: jax.tree.map(lambda a: spec(a.shape, a.dtype), t)
+    params = as_spec(lm.param_specs())
+    cache = as_spec(lm.init_cache(slots, S, abstract=True))
+    text = jax.jit(fused, donate_argnums=(1,)).lower(
+        params, cache, spec((slots,), jnp.int32),
+        spec((slots,), jnp.bool_)).compile().as_text()
+    assert "tpu_custom_call" in text
+    stack = f"bf16[{cfg.num_layers},{slots},{S},{HKV},{D}]"
+    assert not [t for t in _outputs(text, "copy") if stack in t]
+    layer = (f"bf16[{slots},{HKV},{S},{D}]", f"bf16[{slots},{S},{HKV},{D}]")
+    assert not [t for t in _outputs(text, "fusion")
+                if any(shape in t for shape in layer)]
 
 
 def test_paged_decode_attention_compiles(spec):
