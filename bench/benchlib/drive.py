@@ -52,7 +52,7 @@ class Req:
     sent: Optional[float] = None
     rid: Optional[str] = None
     done: Optional[object] = None   # the ServeComplete
-    failed: str = ""
+    failed: str = ""                # "<reason>: <detail>"
 
     @property
     def ok(self) -> bool:
@@ -211,7 +211,7 @@ class Driver:
             s.dead = True
             return
         if r.rid is None:
-            r.failed = "refused by admission control"
+            r.failed = "refused: by admission control"
             return
         self.by_rid[r.rid] = r
 
@@ -306,7 +306,7 @@ class Driver:
                            if r.in_window and r.done is None and not r.failed]
                 if not pending or now >= w[1] + DRAIN_S:
                     for r in pending:
-                        r.failed = "no answer within the drain"
+                        r.failed = "drain: no answer within it"
                     break
             if not self._busy() and heap:
                 time.sleep(min(max(heap[0][0] - now, 0.0), 0.05))

@@ -15,7 +15,9 @@ bucket and decode chunk the traffic reaches on every site engine, serve a
 warm stretch of the traffic, then measure for ``--seconds`` seconds of the
 same traffic and wait for the answers due in that window. With ``--trace 1``
 the window is traced with the JAX profiler and the per-layer metrics are
-reported instead of the end-to-end ones. After the window the server is
+reported instead of the end-to-end ones; the profiler stops once those
+answers are in, and the readings are clipped to the window's
+``bench.window`` span. After the window the server is
 freed and a sample of the finished requests is compared with the plain
 float32 reference (``benchlib/reference.py``).
 
@@ -343,12 +345,16 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, devices, peak,
         marks["queue_close"] = queued()
         if trace:
             marks["span"].__exit__(None, None, None)
-            jax.profiler.stop_trace()
 
     log("serving the traffic")
     d.run(warm_s, seconds, closed, plans, on_open=on_open,
           on_close=on_close)
     log("answers due in the window are in")
+    if trace:
+        # stopped only now: writing the trace blocks the host for seconds,
+        # and inside the loop no session would be heartbeated meanwhile
+        jax.profiler.stop_trace()
+        log("profiler stopped")
     mem = peak_bytes(devices)
     e2e = end_to_end(d, marks["setup_s"])
     wreq = d.window_requests()
@@ -365,10 +371,13 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, devices, peak,
         f"queued_at_open={marks['queue_open']} "
         f"queued_at_close={marks['queue_close']} "
         f"memory_peak_bytes={mem} warmed={json.dumps(warm)}")
+    failed_by = {}                  # reason (``Req.failed`` to its colon)
     for r in wreq:
-        if r.failed:
+        if not r.ok:
             say(f"window: failed session {r.session} turn {r.turn}: "
                 f"{r.failed}")
+            key = r.failed.split(":", 1)[0]
+            failed_by[key] = failed_by.get(key, 0) + 1
     result = {}
     dev = {"platform": devices[0].platform, "kind": devices[0].device_kind,
            "count": len(devices), "memory_peak_bytes": mem}
@@ -412,7 +421,8 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, devices, peak,
                          "queued_at_close": marks["queue_close"],
                          "compiles": cin["compiles"],
                          "generator_late_ms_p95": pct(late, 95),
-                         "anchors": d_anchors, "e2e": e2e},
+                         "anchors": d_anchors, "failed_by": failed_by,
+                         "e2e": e2e},
               **result, "compared": cmp}
     return result
 

@@ -69,6 +69,28 @@ def test_sound_run_is_correct(workload):
     assert res["attempted"] > 0 and res["failed"] == 0
 
 
+def test_unanswered_requests_fail_by_reason(monkeypatch):
+    """Answers lost once the window has closed: the requests still queued
+    or running then get none within the drain, and each counts as failed,
+    under the reason ``drain`` in the result's ``failed_by``."""
+    from benchlib import drive
+
+    def lossy(self, _f=drive.Driver._step):
+        done = _f(self)
+        if self.clock.now() < self.window[1]:
+            return done
+        for r in done:
+            r.done, r.failed = None, ""
+        return []
+
+    monkeypatch.setattr(drive.Driver, "_step", lossy)
+    monkeypatch.setattr(drive, "DRAIN_S", 0.5)
+    res = run_small("minitron-8b-4L.long-decode", seed=23)
+    assert res["failed"] > 0
+    assert res["window"]["failed_by"] == {"drain": res["failed"]}
+    assert not res["correct"]
+
+
 @pytest.mark.parametrize("fault", [state_unchanged, half_batch,
                                    token_altered])
 @pytest.mark.parametrize("workload", CELLS)

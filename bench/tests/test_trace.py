@@ -64,6 +64,32 @@ def test_busy_idle_and_kernel_time_by_hand():
     assert gaps[2] == ["engine.decode_round", pytest.approx(0.010)]
 
 
+def test_events_after_the_window_are_left_out():
+    """The profiler stops after the drain, so the trace runs on past the
+    window's end: ops, programs and spans that start after it change
+    nothing that is read."""
+    tr, ms = _hand()
+    want = TR.reduce(tr)
+    late = TR.Trace(ops={k: list(v) for k, v in tr.ops.items()},
+                    modules={k: list(v) for k, v in tr.modules.items()},
+                    spans=list(tr.spans))
+    late.ops["/device:TPU:0"] += [(140 * ms, 180 * ms, "fusion.1"),
+                                  (150 * ms, 170 * ms, "decode_attention_k"),
+                                  (190 * ms, 250 * ms, "drain_only")]
+    late.modules["/device:TPU:0"] += [(140 * ms, 180 * ms,
+                                       "jit__fused_impl(7)")]
+    late.spans += [(100 * ms, 260 * ms, "plane.round"),
+                   (135 * ms, 185 * ms, "engine.decode_round"),
+                   (186 * ms, 188 * ms, "ais.heartbeat")]
+    red = TR.reduce(late)
+    assert red == want
+    assert TR.op_seconds(late, red, lambda n: "decode_attention" in n) \
+        == pytest.approx(0.010)
+    assert TR.module_seconds(late, red,
+                             lambda n: n.startswith("jit__fused_impl(")) \
+        == pytest.approx(0.055)
+
+
 def test_no_device_ops_reads_zero_busy():
     tr = TR.Trace(spans=[(0, 10, "bench.window")])
     red = TR.reduce(tr)
