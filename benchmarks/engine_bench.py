@@ -57,15 +57,12 @@ class SeedLoopEngine:
     """Faithful reimplementation of the pre-PR engine hot loop (the "before"
     arm): one jitted ``decode_step`` per token with NO buffer donation, an
     eager ``jnp.argmax`` dispatch, and a device→host token transfer every
-    step — plus the seed's per-row batched-scatter decode-cache insert
-    (``decode_cache_scatter=True``), which XLA serialises on CPU."""
+    step."""
 
     def __init__(self, cfg, params, slots, max_len):
-        import dataclasses
         import jax
         import jax.numpy as jnp
         from repro.models.transformer import LM
-        cfg = dataclasses.replace(cfg, decode_cache_scatter=True)
         self.cfg = cfg
         self.lm = LM(cfg)
         self.params = params

@@ -354,8 +354,8 @@ def decode_self_attention(p, cfg: ModelConfig, x, cache_k, cache_v, position,
     rows: parked (idle-resident) sessions ride the fused batch without their
     state advancing, which is what makes in-place hibernation-tier parking
     safe for ring buffers (a masked row would otherwise overwrite a live
-    in-window entry) and costs nothing — the mask folds into the existing
-    select-write.
+    in-window entry). The mask selects between each row's new and old
+    entry, [b, kh, hd], before the write.
     """
     b = x.shape[0]
     position = jnp.broadcast_to(jnp.asarray(position, jnp.int32), (b,))
@@ -366,53 +366,41 @@ def decode_self_attention(p, cfg: ModelConfig, x, cache_k, cache_v, position,
     if not stacked:                       # one layer's buffers: a stack of 1
         cache_k, cache_v, layer = cache_k[None], cache_v[None], 0
     with jax.named_scope("kv_write"):
-        k_layer, v_layer = _decode_cache_write(
-            cfg, jax.lax.dynamic_index_in_dim(cache_k, layer, 0, False),
-            jax.lax.dynamic_index_in_dim(cache_v, layer, 0, False),
-            k_new, v_new, position, window, active)
-        cache_k = jax.lax.dynamic_update_index_in_dim(
-            cache_k, k_layer.astype(cache_k.dtype), layer, 0)
-        cache_v = jax.lax.dynamic_update_index_in_dim(
-            cache_v, v_layer.astype(cache_v.dtype), layer, 0)
+        cache_k = _decode_cache_write(cache_k, k_new, layer, position, window,
+                                      active)
+        cache_v = _decode_cache_write(cache_v, v_new, layer, position, window,
+                                      active)
     with jax.named_scope("attn"):
         if cfg.use_pallas_decode and not window \
                 and not cfg.attn_logits_softcap:
             out = _decode_attend_kernel(p, cfg, x, q, cache_k, cache_v,
                                         layer, position)
         else:
-            out = _decode_attend(p, cfg, x, q, k_layer, v_layer, position,
-                                 window)
+            out = _decode_attend(
+                p, cfg, x, q,
+                jax.lax.dynamic_index_in_dim(cache_k, layer, 0, False),
+                jax.lax.dynamic_index_in_dim(cache_v, layer, 0, False),
+                position, window)
     if not stacked:
         return out, cache_k[0], cache_v[0]
     return out, cache_k, cache_v
 
 
-def _decode_cache_write(cfg: ModelConfig, cache_k, cache_v, k_new, v_new,
-                        position, window, active):
-    """Write each row's new K/V at its position of the dense buffer."""
-    b, S = cache_k.shape[0], cache_k.shape[1]
+def _decode_cache_write(stack, new, layer, position, window, active):
+    """Write each row's new entry ``new`` [b, 1, kh, hd] into layer
+    ``layer`` of ``stack`` [L, b, S, kh, hd] at the row's slot: one row per
+    batch row, so the stack is updated in place and nothing else of it is
+    read or written. Ring buffers take slot ``position % S``, linear ones
+    clamp at ``S - 1``."""
+    b, S = stack.shape[1], stack.shape[2]
     slot = (position % S) if window else jnp.minimum(position, S - 1)
-    if cfg.decode_cache_scatter:          # legacy insert (A/B lever)
-        rows = jnp.arange(b)
-        ck = cache_k.at[rows, slot].set(k_new[:, 0])
-        cv = cache_v.at[rows, slot].set(v_new[:, 0])
-        if active is not None:
-            act = active[:, None, None, None]
-            ck = jnp.where(act, ck, cache_k)
-            cv = jnp.where(act, cv, cache_v)
-        cache_k, cache_v = ck, cv
-    else:
-        # masked write instead of a batched scatter: XLA lowers per-row
-        # scatter to a serial loop on CPU (and an expensive scatter on
-        # TPU), while the select is one bandwidth-bound fused op
-        hit = (jnp.arange(S, dtype=jnp.int32)[None, :]
-               == slot[:, None])
-        if active is not None:
-            hit = hit & active[:, None]
-        hit = hit[..., None, None]
-        cache_k = jnp.where(hit, k_new, cache_k)
-        cache_v = jnp.where(hit, v_new, cache_v)
-    return cache_k, cache_v
+    rows = jnp.arange(b)
+    new = new[:, 0].astype(stack.dtype)
+    if active is not None:
+        new = jnp.where(active[:, None, None], new, stack[layer, rows, slot])
+    # (layer, row, slot) index triples are unique and sorted: rows differ
+    return stack.at[layer, rows, slot].set(new, unique_indices=True,
+                                           indices_are_sorted=True)
 
 
 def _decode_attend_kernel(p, cfg: ModelConfig, x, q, stack_k, stack_v, layer,

@@ -81,10 +81,6 @@ class ModelConfig:
     #: sliding-window rings and softcapped logits, which the kernel doesn't
     #: implement
     use_pallas_decode: bool = False
-    #: legacy per-row batched-scatter decode-cache insert (XLA lowers it to
-    #: a serial loop on CPU); kept as an A/B lever for engine_bench — the
-    #: default is the fused select write
-    decode_cache_scatter: bool = False
     serve_embed_replicated: bool = False
     serve_fsdp_mode: str = "auto"    # auto | on | off — weight-gathered serve
     serve_weight_dtype: str = "bfloat16"  # bfloat16 | int8 (weight-only quant)

@@ -279,6 +279,100 @@ class TestPallasDecodeRoute:
             np.testing.assert_array_equal(np.asarray(nk)[others],
                                           np.asarray(sk)[others])
 
+    # name: (stack depth, layer or None for a layer's own buffer, S,
+    #        window (0 = linear buffer, else a ring of S), positions, active)
+    ROW_WRITE_CASES = {
+        "first-layer": (3, 0, 40, 0, [7, 39, 0], None),
+        "last-layer": (3, 2, 40, 0, [7, 39, 0], None),
+        "stack-of-one": (1, None, 40, 0, [5, 30, 12], None),
+        "ring-past-S": (2, 1, 16, 16, [16, 37, 9], None),
+        "linear-clamp": (3, 1, 24, 0, [23, 24, 60], None),
+        "parked-rows": (3, 1, 40, 0, [7, 12, 33], [True, False, False]),
+    }
+
+    @pytest.mark.parametrize("pallas", [False, True],
+                             ids=["reference", "pallas"])
+    @pytest.mark.parametrize("case", list(ROW_WRITE_CASES))
+    def test_row_write_matches_numpy(self, case, pallas):
+        """Decode writes each row's new K/V at its slot (``position % S``
+        on a ring, ``min(position, S - 1)`` on a linear buffer) of the
+        given layer, and nothing else: other layers, other slots and every
+        entry of a parked row stay bitwise unchanged. The attention reads
+        the written buffer, and a stacked layer decodes exactly as its own
+        buffer does."""
+        import jax
+        import jax.numpy as jnp
+        from repro.models import attention as A
+
+        depth, layer, S, window, pos, act = self.ROW_WRITE_CASES[case]
+        cfg = dataclasses.replace(get_config("edge-tiny"),
+                                  use_pallas_decode=pallas)
+        kh, hd = cfg.num_kv_heads, cfg.head_dim
+        p = A.attention_init(jax.random.key(0), cfg)
+        b = len(pos)
+        x = jax.random.normal(jax.random.key(1), (b, 1, cfg.d_model),
+                              jnp.float32).astype(jnp.bfloat16)
+        shape = (depth, b, S, kh, hd)
+        sk = jax.random.normal(jax.random.key(2), shape,
+                               jnp.float32).astype(jnp.bfloat16)
+        sv = jax.random.normal(jax.random.key(3), shape,
+                               jnp.float32).astype(jnp.bfloat16)
+        pos = jnp.array(pos, jnp.int32)
+        active = None if act is None else jnp.array(act)
+        if layer is None:
+            out, nk, nv = A.decode_self_attention(
+                p, cfg, x, sk[0], sv[0], pos, window=window, active=active)
+            nk, nv, li = nk[None], nv[None], 0
+        else:
+            out, nk, nv = A.decode_self_attention(
+                p, cfg, x, sk, sv, pos, window=window, active=active,
+                layer=jnp.int32(layer))
+            li = layer
+
+        # the NumPy reference of the write
+        k_new, v_new = A._project_kv(p, cfg, x, pos[:, None])
+        p_np = np.asarray(pos)
+        slot = p_np % S if window else np.minimum(p_np, S - 1)
+        live = np.ones(b, bool) if act is None else np.array(act)
+        want_k, want_v = np.array(sk), np.array(sv)
+        for r in np.flatnonzero(live):
+            want_k[li, r, slot[r]] = np.asarray(k_new)[r, 0]
+            want_v[li, r, slot[r]] = np.asarray(v_new)[r, 0]
+        np.testing.assert_array_equal(np.asarray(nk).view(np.uint16),
+                                      want_k.view(np.uint16))
+        np.testing.assert_array_equal(np.asarray(nv).view(np.uint16),
+                                      want_v.view(np.uint16))
+
+        # the NumPy reference of the attention over the written layer
+        q = np.asarray(A._project_q(p, cfg, x, pos[:, None]), np.float32)
+        g = cfg.num_heads // kh
+        qh = q.reshape(b, kh, g, hd)
+        kl = want_k[li].astype(np.float32)
+        vl = want_v[li].astype(np.float32)
+        idx = np.arange(S)
+        if window:
+            kpos = p_np[:, None] - ((p_np[:, None] - idx[None]) % S)
+            valid = (kpos >= 0) & (kpos > p_np[:, None] - window)
+        else:
+            valid = idx[None] <= p_np[:, None]
+        sc = np.einsum("bhgd,bkhd->bhgk", qh, kl) / np.sqrt(hd)
+        sc = np.where(valid[:, None, None], sc, -np.inf)
+        w = np.exp(sc - sc.max(-1, keepdims=True))
+        w /= w.sum(-1, keepdims=True)
+        o = np.einsum("bhgk,bkhd->bhgd", w, vl).reshape(b, cfg.q_dim)
+        want_out = o @ np.asarray(p["w_o"], np.float32)
+        np.testing.assert_allclose(np.asarray(out, np.float32)[:, 0],
+                                   want_out, atol=2e-2, rtol=2e-2)
+
+        if layer is not None:                   # as the layer's own buffer
+            o_one, ck, cv = A.decode_self_attention(
+                p, cfg, x, sk[layer], sv[layer], pos, window=window,
+                active=active)
+            np.testing.assert_array_equal(np.asarray(o_one, np.float32),
+                                          np.asarray(out, np.float32))
+            np.testing.assert_array_equal(np.asarray(ck).view(np.uint16),
+                                          want_k[layer].view(np.uint16))
+
     def test_window_and_softcap_fall_back_to_reference(self):
         """The kernel only implements linear buffers without softcap; the
         flag must be a no-op for ring-buffer / softcapped configs (hybrid

@@ -67,19 +67,17 @@ def _outputs(text: str, op: str) -> list:
             if f" {op}(" in line and " = " in line]
 
 
-def test_decode_step_reads_the_stacked_cache_in_place(spec, monkeypatch):
-    """The fused decode program of a dense model (the engine's jitted scan
-    of ``LM.decode_step``, cache donated) hands the Pallas kernel the
-    stacked cache itself: no copy of the whole stack (a relayout for a
-    kernel operand) and no fusion that outputs one layer's K or V."""
+def _compiled_fused_decode(spec, monkeypatch, layers, slots):
+    """The fused decode program of a dense model at the kv widths above:
+    the engine's jitted scan of ``LM.decode_step`` (``_fused_impl``), cache
+    donated, compiled for the described chip. Returns (cfg, compiled)."""
     from repro.models.config import ModelConfig
     from repro.models.transformer import LM
 
-    slots = 4                   # unlike HKV, so each layout has its shape
-    cfg = ModelConfig(name="dense-2L", family="dense", num_layers=2,
-                      d_model=256, num_heads=2 * HKV, num_kv_heads=HKV,
-                      head_dim=D, d_ff=512, vocab_size=512, remat="none",
-                      use_pallas_decode=True)
+    cfg = ModelConfig(name=f"dense-{layers}L", family="dense",
+                      num_layers=layers, d_model=256, num_heads=2 * HKV,
+                      num_kv_heads=HKV, head_dim=D, d_ff=512, vocab_size=512,
+                      remat="none", use_pallas_decode=True)
     lm = LM(cfg)
     # the call site picks interpret mode from the backend it sees
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
@@ -97,15 +95,45 @@ def test_decode_step_reads_the_stacked_cache_in_place(spec, monkeypatch):
     as_spec = lambda t: jax.tree.map(lambda a: spec(a.shape, a.dtype), t)
     params = as_spec(lm.param_specs())
     cache = as_spec(lm.init_cache(slots, S, abstract=True))
-    text = jax.jit(fused, donate_argnums=(1,)).lower(
+    return cfg, jax.jit(fused, donate_argnums=(1,)).lower(
         params, cache, spec((slots,), jnp.int32),
-        spec((slots,), jnp.bool_)).compile().as_text()
+        spec((slots,), jnp.bool_)).compile()
+
+
+def test_decode_step_reads_the_stacked_cache_in_place(spec, monkeypatch):
+    """The fused decode program of a dense model hands the Pallas kernel the
+    stacked cache itself: no copy of the whole stack (a relayout for a
+    kernel operand) and no fusion that outputs one layer's K or V."""
+    slots = 4                   # unlike HKV, so each layout has its shape
+    cfg, compiled = _compiled_fused_decode(spec, monkeypatch, 2, slots)
+    text = compiled.as_text()
     assert "tpu_custom_call" in text
     stack = f"bf16[{cfg.num_layers},{slots},{S},{HKV},{D}]"
     assert not [t for t in _outputs(text, "copy") if stack in t]
     layer = (f"bf16[{slots},{HKV},{S},{D}]", f"bf16[{slots},{S},{HKV},{D}]")
     assert not [t for t in _outputs(text, "fusion")
                 if any(shape in t for shape in layer)]
+
+
+def test_decode_step_writes_the_new_rows_in_place(spec, monkeypatch):
+    """At the benchmark cell's cache layout (4 layers, 32 slots, 2048
+    positions, 8 kv heads of 128) a decode step writes only its new K/V
+    rows into the stacks: no select over a whole layer or stack feeds the
+    write, and the program's accessed bytes stay below one K stack's
+    bytes (a whole-layer select reads and writes every layer of both)."""
+    slots = 32
+    cfg, compiled = _compiled_fused_decode(spec, monkeypatch, LAYERS, slots)
+    text = compiled.as_text()
+    assert "select_dynamic-update-slice" not in text
+    planes = (f"bf16[{LAYERS},{slots},{S},{HKV},{D}]",
+              f"bf16[1,{slots},{S},{HKV},{D}]",
+              f"bf16[{slots},{S},{HKV},{D}]")
+    assert not [t for t in _outputs(text, "select")
+                if any(shape in t for shape in planes)]
+    from repro.launch.hlo_analysis import normalize_cost_analysis
+    accessed = normalize_cost_analysis(compiled.cost_analysis())
+    stack_bytes = LAYERS * slots * S * HKV * D * 2
+    assert accessed["bytes accessed"] < stack_bytes
 
 
 def test_paged_decode_attention_compiles(spec):
